@@ -826,7 +826,6 @@ def make_spmd_diffuse(mesh, prog: VertexProgram, sg_template,
     import types as _types
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     if isinstance(sg_template, ShardedGraph):
         if sg_template.csr_perm is None or (
@@ -872,10 +871,10 @@ def make_spmd_diffuse(mesh, prog: VertexProgram, sg_template,
         jax.tree_util.tree_map(lambda _: P(axis_name), vstate_struct),
         DiffuseStats(*[P()] * len(DiffuseStats._fields)),
     )
-    return shard_map(
+    return jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )

@@ -542,12 +542,10 @@ class ShardedGraph:
         ``searchsorted`` calls.  Dead slots fill the tail in ascending
         slot order, reproducing the full rebuild bit for bit.  Pure jnp
         and shape-static."""
-        from jax.experimental import enable_x64
-
         # the (key, slot) composites need 64-bit ints at scale; every
         # *stored* array stays int32/bool — only jitted intermediates are
         # wide, so the x64 flag never leaks outside this call
-        with enable_x64():
+        with jax.enable_x64():
             key, perm, inv, psrc, pperm, pinv, ppos = _merge_compact_views(
                 self.csr_key, self.csr_perm, self.csr_live,
                 self.push_src, self.push_perm, self.edge_ok,

@@ -9,9 +9,10 @@ per-destination message table:
 * ``"xla"``     — segment ops over the sorted stream (flat for the
   order-free min/max monoids, segmented scan for sum); the default and
   the CPU/GPU production path.
-* ``"pallas"``  — the fused ``kernels/edge_relax`` kernel: vertex block
-  pinned in VMEM across the edge sweep, dense-rank in-block combine
-  (interpret mode off-TPU, so CI exercises the same code path).
+* ``"pallas"``  — the fused ``kernels/edge_relax`` block kernel: emit +
+  dense-rank in-block combine per 128-edge block (interpret mode
+  off-TPU, so CI exercises the same code path).  Min/max programs only
+  on a TPU: sum programs raise ``NotImplementedError`` there.
 
 Orthogonally, ``sweep`` picks the *direction* (DESIGN.md §2.8):
 
@@ -42,6 +43,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .msg import block_any
 
 __all__ = [
     "RELAX_BACKENDS",
@@ -90,9 +93,7 @@ def active_push_blocks(senders, push_src, block_e: int):
         senders = senders.any(axis=-2)
     ok = push_src >= 0
     act = jnp.take_along_axis(senders, jnp.clip(push_src, 0), axis=-1) & ok
-    nb = push_src.shape[-1] // block_e
-    blk = act.reshape(act.shape[:-1] + (nb, block_e)).any(axis=-1)
-    return jnp.sum(blk, axis=-1)
+    return jnp.sum(block_any(act, block_e), axis=-1, dtype=jnp.int32)
 
 
 def select_bucket(n_active_blocks, n_blocks: int, sweep: str,
@@ -167,6 +168,12 @@ def make_relax(prog, n_shards: int, n_per_shard: int, block_e: int,
 
     n_keys = n_shards * n_per_shard
     interpret = backend == "pallas" and jax.default_backend() != "tpu"
+    if backend == "pallas" and not interpret and prog.combine == "sum":
+        raise NotImplementedError(
+            "backend='pallas' has no TPU kernel for sum-combine programs: "
+            "the scan kernel (kernels/edge_relax.edge_relax_scan) needs a "
+            "1-D gather Mosaic does not lower and holds a cell's whole "
+            "edge stream in VMEM; use backend='xla'")
 
     def _shape(table, cnt, pay):
         if prog.lanes:

@@ -680,21 +680,20 @@ class DiffusionSession:
                 f"cell); this process has {len(jax.devices())}. Set "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count={S} "
                 f"before importing jax, or use engine='sharded'.")
-        from ..launch.mesh import mesh_context
-
         # the per-device fn traces prog.init *inside* shard_map, so the
         # cache key needs the init identity on top of the program's
         # (init-excluding) structural equality — see VertexProgram.__eq__
         fkey = (program, _fn_key(program.init), S, backend, sweep)
         if fkey not in self._spmd_fns:
-            mesh = jax.make_mesh((S,), ("cells",))
-            self._spmd_fns[fkey] = (mesh, make_spmd_diffuse(
+            from ..launch.mesh import make_mesh
+
+            mesh = make_mesh((S,), ("cells",))
+            self._spmd_fns[fkey] = make_spmd_diffuse(
                 mesh, program, self.sg, axis_name="cells",
                 max_local_iters=self.max_local_iters,
-                max_rounds=self.max_rounds, backend=backend, sweep=sweep))
-        mesh, fn = self._spmd_fns[fkey]
-        with mesh_context(mesh):
-            return fn(_sg_as_dict(self.sg, with_push=sweep != "pull"))
+                max_rounds=self.max_rounds, backend=backend, sweep=sweep)
+        return self._spmd_fns[fkey](
+            _sg_as_dict(self.sg, with_push=sweep != "pull"))
 
     def _result(self, entry: _Entry) -> Result:
         values = self.to_global(entry.vstate[entry.value_key])

@@ -82,6 +82,11 @@ def _pad(a: np.ndarray, k: int, fill) -> jnp.ndarray:
     return jnp.asarray(out)
 
 
+# edge-delete ops matched against their cell's edge slots per lax.map
+# step: memory is DELETE_MATCH_BATCH x (edge slots per cell)
+DELETE_MATCH_BATCH = 8
+
+
 @partial(jax.jit, static_argnames=("stage",))
 def apply_updates(sg, ops: dict, stage: bool):
     """The whole batched apply as one compiled program.
@@ -114,19 +119,24 @@ def apply_updates(sg, ops: dict, stage: bool):
     if "ed_su" in ops:
         su, lu, vg, occ = (ops["ed_su"], ops["ed_lu"], ops["ed_vg"],
                            ops["ed_occ"])
-        match = (
-            (sg.src_local[su] == lu[:, None])
-            & (sg.dst_gid[su] == vg[:, None])
-            & sg.edge_ok[su]
-        )                                                   # [K, Ep]
-        # the occ-th occurrence of a pair takes the occ-th matching slot
-        # (first-match semantics): the slot where the running match count
-        # reaches occ+1 — a cumsum + argmax, not a per-row argsort (whose
-        # O(K Ep log Ep) would rival the full rebuild this path replaces)
-        hit = match & (jnp.cumsum(match, axis=1) == (occ + 1)[:, None])
-        slot = jnp.argmax(hit, axis=1).astype(i32)
-        rows = jnp.arange(su.shape[0])
-        del_ok = hit[rows, slot]
+
+        def match_one(op):
+            s, l, v, o = op
+            match = ((sg.src_local[s] == l) & (sg.dst_gid[s] == v)
+                     & sg.edge_ok[s])                       # [Ep]
+            # the occ-th occurrence of a pair takes the occ-th matching
+            # slot (first-match semantics): the slot where the running
+            # match count reaches occ+1 — a cumsum + argmax, not a
+            # per-row argsort (whose O(Ep log Ep) per op would rival the
+            # full rebuild this path replaces)
+            hit = match & (jnp.cumsum(match, dtype=i32) == o + 1)
+            slot = jnp.argmax(hit).astype(i32)
+            return slot, hit[slot]
+
+        # a few ops at a time: all K rows at once is a [K, Ep] table
+        # (64 GB for 2,048 deletes at Graph500 scale 20)
+        slot, del_ok = jax.lax.map(match_one, (su, lu, vg, occ),
+                                   batch_size=DELETE_MATCH_BATCH)
         # non-matching rows would land on an arbitrary live slot and race
         # with real deletes at the same index (duplicate scatter indices
         # with conflicting values are unordered in XLA) — route them out

@@ -11,7 +11,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["make_pipeline_fn", "bubble_fraction"]
@@ -57,10 +56,10 @@ def make_pipeline_fn(mesh, stage_fn, n_stages: int, n_micro: int,
         # only the last stage wrote outputs; psum replicates them
         return lax.psum(ys, axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

@@ -6,11 +6,14 @@ The paper's central claim is that dynamic graph processing should be
 a central processor.  This package is that claim expressed at the kernel
 level, for the engine's hot loop (one relaxation sweep of one cell):
 
-* the cell's **vertex block is the resident operand** — in the Pallas
-  kernel it is pinned in VMEM for the entire edge sweep, exactly the
-  paper's "computation moves to where the vertex data lives" (and the
-  Dalorex/Rhizomes argument that fusing gather→combine→scatter at the data
-  is where memory-bound graph workloads win);
+* the cell's **vertex block is the operand the stream is relaxed
+  against** — the paper's "computation moves to where the vertex data
+  lives" (and the Dalorex/Rhizomes argument that fusing
+  gather→combine→scatter at the data is where memory-bound graph
+  workloads win).  Neither backend keeps it resident in VMEM: Mosaic
+  lowers no gather from a vertex block held there, so on a TPU the
+  per-edge reads of it are one XLA gather from HBM ahead of the Pallas
+  kernel, which only emits and combines;
 * the **edge stream is the moving operand** — it arrives in the graph's
   destination-sorted blocked-CSR layout (``ShardedGraph.with_csr``), so
   each block's messages form contiguous per-destination runs and the

@@ -2,39 +2,44 @@
 hot loop as one kernel.
 
 Two kernels share the engine's relaxation step (gather ``vstate[src]`` →
-``prog.emit`` → segment-combine by destination), fused into a single
-VMEM-resident pipeline, generalized to every registered combine
-:class:`~repro.core.monoid.Monoid` and the payload path:
+``prog.emit`` → segment-combine by destination), generalized to every
+registered combine :class:`~repro.core.monoid.Monoid` and the payload
+path:
 
 * :func:`edge_relax_blocks` — the blocked dense-rank kernel (min/max
-  single-query path; per 128-edge block, grid-parallel);
+  single-query path; per 128-edge block, grid-parallel), also the body of
+  the frontier-compacted push sweep (:func:`edge_relax_push_blocks`);
 * :func:`edge_relax_scan` — the segmented-scan kernel (sum programs and
   multi-query lanes; whole stream resident, ``ref.stream_scan`` body
-  executed verbatim for bitwise parity with the XLA path).
+  executed verbatim for bitwise parity with the XLA path).  Off-TPU
+  only: Mosaic has no 1-D gather from VMEM and the stream does not fit
+  VMEM at deployment size, so ``relax.make_relax`` refuses it on a TPU.
 
 Blocked-kernel anatomy:
 
-* the **vertex block stays pinned in VMEM** across the whole edge stream —
-  the paper's memory-driven execution model: compute (the edge sweep) moves
-  to where the vertex data lives, instead of three XLA scatter passes each
-  re-streaming the vertex arrays through HBM;
+* the per-edge source reads (``vstate[src]``, ``senders[src]``,
+  ``gid[src]``) are one XLA gather ahead of the kernel — Mosaic lowers
+  only 2-D gathers within a tile, not a gather from a whole vertex block
+  held in VMEM;
 * edges arrive in the graph's **blocked-CSR layout** (sorted by
   ``(dst_shard, dst_local)``, ``-1``-padded to a block multiple — see
   ``ShardedGraph.with_csr``), so each block's combine is the dense-rank
   one-hot reduction shared with ``segment_reduce`` (MXU-shaped for sum);
 * ``prog.emit`` / ``prog.payload`` are traced *into* the kernel body, so any
   registered vertex program (SSSP / BFS / CC / PPR / PageRank) runs on this
-  path unchanged.
+  path unchanged;
+* every stream, inputs and outputs alike, is one ``[1, E]`` row tiled in
+  ``(1, block_e)`` blocks: a block whose leading dim equals the array's
+  satisfies the TPU (8, 128) tiling rule.
 
 Phase 2 (cross-block combine of the tiny per-block partial tables) is XLA
 code shared with the reference — see ops.py.  The per-block math itself
 lives in ref.py (:func:`~.ref.block_combine`) and is executed verbatim here,
 which is what makes the two backends bitwise-interchangeable.
 
-Interpret-mode caveat: on CPU/GPU (CI) the kernel runs under
-``pl.pallas_call(..., interpret=True)`` — same ops, no Mosaic lowering — so
-the bitwise backend-equivalence tests run everywhere; compiled TPU execution
-additionally wants ``n_per_shard`` padded to the lane width.
+Off-TPU (CI) the kernels run under ``pl.pallas_call(..., interpret=True)``
+— same ops, no Mosaic lowering — so the bitwise backend-equivalence tests
+run everywhere.
 """
 
 from __future__ import annotations
@@ -44,24 +49,31 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams as _CompilerParams
-from .ref import block_combine, edge_messages, stream_scan
+from .ref import (
+    block_combine,
+    edge_messages,
+    emit_messages,
+    gather_sources,
+    push_gather,
+    stream_scan,
+)
 
 __all__ = ["edge_relax_blocks", "edge_relax_scan", "edge_relax_push_blocks"]
 
 
 def _kernel(*refs, prog, treedef, n_leaves: int, block_e: int):
-    vrefs = refs[:n_leaves]
-    senders_ref, gid_ref, key_ref, src_ref, w_ref, dstg_ref = (
-        refs[n_leaves:n_leaves + 6]
+    srefs = refs[:n_leaves]
+    send_ref, sgid_ref, key_ref, w_ref, dstg_ref = (
+        refs[n_leaves:n_leaves + 5]
     )
-    outs = refs[n_leaves + 6:]
-    vstate = jax.tree_util.tree_unflatten(
-        treedef, [r[0] for r in vrefs]      # [Np] leaves, VMEM-resident
+    outs = refs[n_leaves + 5:]
+    src_state = jax.tree_util.tree_unflatten(
+        treedef, [r[0] for r in srefs]      # [block_e] gathered reads
     )
-    cand, send, pay = edge_messages(
-        prog, vstate, senders_ref[0], gid_ref[0], key_ref[0], src_ref[0],
+    cand, send, pay = emit_messages(
+        prog, src_state, send_ref[0] != 0, sgid_ref[0], key_ref[0],
         w_ref[0], dstg_ref[0],
     )
     part, cnt, uniq, pay_part = block_combine(
@@ -142,79 +154,28 @@ def edge_relax_scan(prog, vstate, senders, gid, key, src, weight, dst_gid,
     return v, c, p
 
 
-def _push_kernel(idx_ref, *refs, prog, treedef, n_leaves: int, block_e: int):
-    # same body as the dense blocked kernel — the grid walks the *active
-    # block list* instead of every block (idx_ref is the scalar-prefetched
-    # compaction; the BlockSpec index maps consumed it before this body
-    # runs, so the refs already hold the gathered block)
-    del idx_ref
-    _kernel(*refs, prog=prog, treedef=treedef, n_leaves=n_leaves,
-            block_e=block_e)
-
-
-def edge_relax_push_blocks(prog, vstate, senders, gid, key, src, weight,
-                           dst_gid, idx, block_e: int,
-                           interpret: bool = False):
+def edge_relax_push_blocks(prog, vstate, senders, gid, sg_push, idx,
+                           block_e: int, interpret: bool = False):
     """Frontier-compacted Pallas sweep: per-block partial tables for the
     ``cap = len(idx)`` active blocks of the *source-sorted* push stream.
 
     ``idx`` is the compacted active-block list
-    (:func:`~.ref.compact_push_blocks`; fill slots carry ``nb``).  It is
-    scalar-prefetched, and the edge-stream BlockSpecs index through it —
-    grid step ``i`` DMAs block ``idx[i]`` — so only the frontier's blocks
-    ever leave HBM; the vertex block stays pinned in VMEM as in the dense
-    kernel.  Fill slots clamp to the last block and must be neutralized
-    by the caller (``ops._mask_fill_blocks``) before the cross-block
-    combine — a duplicated block is harmless for the idempotent min/max
-    values but would double the sending-edge counts.
+    (:func:`~.ref.compact_push_blocks`; fill slots carry ``nb``).  The
+    shared :func:`~.ref.push_gather` pulls only those blocks out of the
+    push streams (``sg_push``, ``ShardedGraph.push_view``) and reads
+    their fill positions as dead (key ``-1``), so fills contribute no
+    message and no count; the dense block kernel then runs over the
+    ``cap`` gathered blocks.
 
     Push blocks are not destination-sorted, so a destination may occupy
     several dense ranks within one block; the shared phase-2 scatter
     merges them (order-free min/max), keeping push bitwise-equal to the
-    dense paths.  Returns (part, cnt, uniq[, pay]) each [cap, block_e].
+    dense paths.  Returns (part, cnt, uniq[, pay]) each [cap * block_e].
     """
-    from jax.experimental.pallas import tpu as pltpu
-
-    leaves, treedef = jax.tree_util.tree_flatten(vstate)
-    np_ = gid.shape[0]
-    e = key.shape[0]
-    assert e % block_e == 0, "pad the stream via ShardedGraph.with_csr"
-    nb = e // block_e
-    cap = idx.shape[0]
-
-    pinned = lambda: pl.BlockSpec((1, np_), lambda i, idx: (0, 0))
-    stream = lambda: pl.BlockSpec(
-        (1, block_e), lambda i, idx: (0, jnp.minimum(idx[i], nb - 1)))
-    out_blk = lambda: pl.BlockSpec((1, block_e), lambda i, idx: (i, 0))
-
-    n_out = 4 if prog.with_payload else 3
-    out_dtypes = [prog.msg_dtype, jnp.int32, jnp.int32, jnp.int32][:n_out]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(cap,),
-        in_specs=(
-            [pinned() for _ in leaves]          # vstate: whole cell, pinned
-            + [pinned(), pinned()]              # senders, gid
-            + [stream() for _ in range(4)]      # key, src, weight, dst_gid
-        ),
-        out_specs=[out_blk() for _ in range(n_out)],
-    )
-    outs = pl.pallas_call(
-        functools.partial(_push_kernel, prog=prog, treedef=treedef,
-                          n_leaves=len(leaves), block_e=block_e),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((cap, block_e), dt)
-                   for dt in out_dtypes],
-        interpret=interpret,
-    )(
-        idx,
-        *[leaf[None] for leaf in leaves],
-        senders[None], gid[None],
-        key[None], src[None], weight[None], dst_gid[None],
-    )
-    part, cnt, uniq = outs[0], outs[1], outs[2]
-    pay = outs[3] if prog.with_payload else None
-    return part, cnt, uniq, pay
+    g, _ = push_gather(sg_push, idx, block_e)
+    return edge_relax_blocks(prog, vstate, senders, gid, g["key"], g["src"],
+                             g["weight"], g["dst_gid"], block_e,
+                             interpret=interpret)
 
 
 def edge_relax_blocks(prog, vstate, senders, gid, key, src, weight, dst_gid,
@@ -223,40 +184,34 @@ def edge_relax_blocks(prog, vstate, senders, gid, key, src, weight, dst_gid,
 
     Inputs are this cell's vertex block ([Np] vstate leaves, ``senders``,
     ``gid``) and its destination-sorted edge streams ([Eb], Eb a multiple
-    of ``block_e``).  Returns (part, cnt, uniq[, pay]) each [nb, block_e] —
+    of ``block_e``).  Returns (part, cnt, uniq[, pay]) each [Eb]: block
+    i's partial table occupies ``[i * block_e, (i + 1) * block_e)`` —
     feed to ``ops._combine_blocks`` for the cross-block phase.
     """
-    leaves, treedef = jax.tree_util.tree_flatten(vstate)
-    np_ = gid.shape[0]
     e = key.shape[0]
     assert e % block_e == 0, "pad the stream via ShardedGraph.with_csr"
-    nb = e // block_e
-
-    pinned = lambda: pl.BlockSpec((1, np_), lambda i: (0, 0))
-    stream = lambda: pl.BlockSpec((1, block_e), lambda i: (0, i))
-    out_blk = lambda: pl.BlockSpec((1, block_e), lambda i: (i, 0))
+    src_state, src_send, src_gid = gather_sources(vstate, senders, gid, src)
+    leaves, treedef = jax.tree_util.tree_flatten(src_state)
+    n_in = len(leaves) + 5
+    stream = pl.BlockSpec((1, block_e), lambda i: (0, i))
 
     n_out = 4 if prog.with_payload else 3
     out_dtypes = [prog.msg_dtype, jnp.int32, jnp.int32, jnp.int32][:n_out]
     outs = pl.pallas_call(
         functools.partial(_kernel, prog=prog, treedef=treedef,
                           n_leaves=len(leaves), block_e=block_e),
-        grid=(nb,),
-        in_specs=(
-            [pinned() for _ in leaves]          # vstate: whole cell, pinned
-            + [pinned(), pinned()]              # senders, gid
-            + [stream() for _ in range(4)]      # key, src, weight, dst_gid
-        ),
-        out_specs=[out_blk() for _ in range(n_out)],
-        out_shape=[jax.ShapeDtypeStruct((nb, block_e), dt)
-                   for dt in out_dtypes],
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        grid=(e // block_e,),
+        in_specs=[stream] * n_in,
+        out_specs=[stream] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((1, e), dt) for dt in out_dtypes],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(
         *[leaf[None] for leaf in leaves],
-        senders[None], gid[None],
-        key[None], src[None], weight[None], dst_gid[None],
+        src_send.astype(jnp.int32)[None], src_gid[None],
+        key[None], weight[None], dst_gid[None],
     )
-    part, cnt, uniq = outs[0], outs[1], outs[2]
-    pay = outs[3] if prog.with_payload else None
+    part, cnt, uniq = (o[0] for o in outs[:3])
+    pay = outs[3][0] if prog.with_payload else None
     return part, cnt, uniq, pay

@@ -34,13 +34,13 @@ from .kernel import edge_relax_blocks, edge_relax_push_blocks, edge_relax_scan
 from .ref import (
     compact_push_blocks,
     delta_tables,
+    edge_messages,
     edge_relax_flat,
     edge_relax_push_flat,
     edge_relax_push_stream,
     edge_relax_stream,
     gather_runs,
     merge_tables,
-    stream_messages,
 )
 
 __all__ = ["edge_relax", "edge_relax_push", "RELAX_BACKENDS"]
@@ -51,8 +51,8 @@ def _combine_blocks(part, cnt, uniq, pay, n_keys: int, combine: str,
     """Phase 2: scatter the per-block partial tables into the flat key
     space — O(blocks * block_e) rows, shared by both backends."""
     ident = identity_for(combine, msg_dtype)
-    ids = jnp.where(uniq < 0, n_keys, uniq).reshape(-1)
-    p = part.reshape(-1)
+    ids = jnp.where(uniq < 0, n_keys, uniq)
+    p = part
     table = jnp.full((n_keys + 1,), ident, msg_dtype)
     if combine == "min":
         table = table.at[ids].min(p)
@@ -60,27 +60,14 @@ def _combine_blocks(part, cnt, uniq, pay, n_keys: int, combine: str,
         table = table.at[ids].max(p)
     else:
         table = table.at[ids].add(p)
-    cnt_t = jnp.zeros((n_keys + 1,), jnp.int32).at[ids].add(cnt.reshape(-1))
+    cnt_t = jnp.zeros((n_keys + 1,), jnp.int32).at[ids].add(cnt)
     pay_t = None
     if pay is not None:
         # winners: block partials equal to the globally combined value
-        win = jnp.where(p == table[ids], pay.reshape(-1), -1)
+        win = jnp.where(p == table[ids], pay, -1)
         pay_t = jnp.full((n_keys + 1,), -1, jnp.int32).at[ids].max(win)
         pay_t = pay_t[:n_keys]
     return table[:n_keys], cnt_t[:n_keys], pay_t
-
-
-def _mask_fill_blocks(part, cnt, uniq, pay, valid):
-    """Neutralize the fill slots of a power-of-two compaction bucket
-    (``cap > n_active`` — their grid steps clamped to a real block whose
-    contribution must not repeat): route their keys off-range and zero
-    their counts so the phase-2 scatter drops them."""
-    v = valid[:, None]
-    uniq = jnp.where(v, uniq, -1)
-    cnt = jnp.where(v, cnt, 0)
-    if pay is not None:
-        pay = jnp.where(v, pay, -1)
-    return part, cnt, uniq, pay
 
 
 def edge_relax_push(prog, vstate, senders, gid, sg_push, csr_key,
@@ -98,7 +85,7 @@ def edge_relax_push(prog, vstate, senders, gid, sg_push, csr_key,
     layout and run the shared scan (``ref.edge_relax_push_stream`` —
     bitwise-equal to the dense scan for the order-sensitive monoid, on
     either backend); single-query min/max takes the unsorted segment path
-    on ``xla`` and the scalar-prefetch blocked kernel on ``pallas``
+    on ``xla`` and the block kernel over the gathered blocks on ``pallas``
     (order-free monoids agree across all paths).  Phase 2 is the same
     shared XLA code as the dense sweep.
 
@@ -121,13 +108,10 @@ def edge_relax_push(prog, vstate, senders, gid, sg_push, csr_key,
     if backend == "xla":
         return edge_relax_push_flat(prog, vstate, senders, gid, sg_push,
                                     n_keys, block_e, cap)
-    idx, valid = compact_push_blocks(senders, sg_push["push_src"], block_e,
-                                     cap)
+    idx, _ = compact_push_blocks(senders, sg_push["push_src"], block_e, cap)
     part, cnt, uniq, pay = edge_relax_push_blocks(
-        prog, vstate, senders, gid, sg_push["push_key"],
-        sg_push["push_src"], sg_push["push_weight"],
-        sg_push["push_dst_gid"], idx, block_e, interpret=interpret)
-    part, cnt, uniq, pay = _mask_fill_blocks(part, cnt, uniq, pay, valid)
+        prog, vstate, senders, gid, sg_push, idx, block_e,
+        interpret=interpret)
     return _combine_blocks(part, cnt, uniq, pay, n_keys, prog.combine,
                            prog.msg_dtype)
 
@@ -179,7 +163,7 @@ def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
         if delta_e:
             # delta tail: shared XLA phase (message bodies + scatter),
             # merged by the monoid — same code the XLA path runs
-            cand, send, pay = stream_messages(
+            cand, send, pay = edge_messages(
                 prog, vstate, senders, gid, key[es:], src[es:],
                 weight[es:], dst_gid[es:])
             out = merge_tables(prog, out, delta_tables(

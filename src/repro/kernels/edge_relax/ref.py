@@ -2,7 +2,7 @@
 
 Two *single-source-of-truth* bodies keep the backends bitwise-identical:
 
-* ``stream_scan`` — the segmented associative scan over the globally
+* ``stream_scan`` — the segmented scan over the globally
   destination-sorted stream.  The Pallas scan kernel
   (:func:`~.kernel.edge_relax_scan`) and the XLA scan path
   (:func:`edge_relax_stream`) execute exactly this function, and its
@@ -13,8 +13,7 @@ Two *single-source-of-truth* bodies keep the backends bitwise-identical:
   path for all multi-query-lane runs.
 * ``block_combine`` — the blocked dense-rank segment combine executed
   verbatim by the blocked Pallas kernel
-  (:func:`~.kernel.edge_relax_blocks`) and the XLA blocked reference
-  (:func:`edge_relax_blocks_ref`).
+  (:func:`~.kernel.edge_relax_blocks`).
 
 ``edge_relax_flat`` is the fast unblocked path for single-query
 order-free monoids (min/max): plain segment ops over the sorted stream.
@@ -27,14 +26,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ...core.msg import identity_for, segment_combine
+from ...core.msg import block_any, identity_for, segment_combine
 
 __all__ = [
     "edge_messages",
-    "stream_messages",
+    "gather_sources",
+    "emit_messages",
     "block_combine",
     "flat_combine",
-    "edge_relax_blocks_ref",
     "edge_relax_flat",
     "stream_scan",
     "gather_runs",
@@ -49,48 +48,45 @@ __all__ = [
 ]
 
 
-def edge_messages(prog, vstate, senders, gid, key, src, weight, dst_gid):
-    """Gather + emit along the destination-sorted edge stream.
-
-    Elementwise: per edge, gather the source vertex state, run the
-    program's ``emit``, and mask non-sending / dead edges to the combine
-    identity (the *monoid's* identity — custom monoids may differ from
-    their scatter class's, and the scan path folds padding through the
-    custom ``op``, where only a true identity is absorbing).  Runs
-    identically inside the Pallas kernels (on VMEM-resident vertex
-    blocks) and in the XLA paths.
-
-    Returns (cand [E] msg_dtype, send [E] bool, pay [E] int32 | None).
-    """
-    src_state = jax.tree_util.tree_map(lambda a: a[src], vstate)
-    valid = key >= 0
-    send = senders[src] & valid
-    msg = prog.emit(src_state, weight, gid[src], dst_gid)
-    ident = prog.monoid.identity(prog.msg_dtype)
-    cand = jnp.where(send, msg, ident).astype(prog.msg_dtype)
-    pay = None
-    if prog.with_payload:
-        pay = prog.payload(src_state, gid[src]).astype(jnp.int32)
-        pay = jnp.where(send, pay, -1)
-    return cand, send, pay
-
-
-def stream_messages(prog, vstate, senders, gid, key, src, weight, dst_gid):
-    """Lane-broadcasting twin of :func:`edge_messages` (``senders`` and
-    vstate leaves may carry leading lane axes).  Shared verbatim by the
-    dense scan path and the push stream path, so a future emit/mask
-    change cannot split them."""
+def gather_sources(vstate, senders, gid, src):
+    """Per-edge reads of the source vertex: (state leaves, sender flag,
+    gid), each [..., E].  ``vstate`` leaves and ``senders`` may carry
+    leading lane axes, which broadcast through."""
     src_state = jax.tree_util.tree_map(lambda a: a[..., src], vstate)
+    return src_state, senders[..., src], gid[src]
+
+
+def emit_messages(prog, src_state, src_send, src_gid, key, weight, dst_gid):
+    """Emit along the edge stream from already-gathered source reads
+    (:func:`gather_sources`): run the program's ``emit``, and mask
+    non-sending / dead edges to the combine identity (the *monoid's*
+    identity — custom monoids may differ from their scatter class's, and
+    the scan path folds padding through the custom ``op``, where only a
+    true identity is absorbing).  Elementwise, so it runs identically
+    inside the Pallas block kernel and in the XLA paths.
+
+    Returns (cand [..., E] msg_dtype, send [..., E] bool,
+    pay [..., E] int32 | None).
+    """
     valid = key >= 0
-    send = senders[..., src] & valid
-    msg = prog.emit(src_state, weight, gid[src], dst_gid)
+    send = src_send & valid
+    msg = prog.emit(src_state, weight, src_gid, dst_gid)
     ident = prog.monoid.identity(prog.msg_dtype)
     cand = jnp.where(send, msg, ident).astype(prog.msg_dtype)
     pay = None
     if prog.with_payload:
-        pay = prog.payload(src_state, gid[src]).astype(jnp.int32)
+        pay = prog.payload(src_state, src_gid).astype(jnp.int32)
         pay = jnp.where(send, pay, -1)
     return cand, send, pay
+
+
+def edge_messages(prog, vstate, senders, gid, key, src, weight, dst_gid):
+    """Gather + emit along the destination-sorted edge stream — the one
+    message body of every path (dense, push, scan, delta).  ``senders``
+    and vstate leaves may carry leading lane axes.  Returns
+    (cand, send, pay) as :func:`emit_messages`."""
+    return emit_messages(prog, *gather_sources(vstate, senders, gid, src),
+                         key, weight, dst_gid)
 
 
 def flat_combine(cand, send, pay, ids, n_keys: int, combine: str):
@@ -122,55 +118,88 @@ def block_combine(cand, send, key, pay, combine: str, block_e: int):
 
     Returns (part [Be], cnt [Be] int32, uniq [Be] int32, pay_part | None).
     """
+    # Edges run down the sublanes of [Be, Be] masks and runs across the
+    # lanes.  The neighbour shift and the run ranks are mask reductions,
+    # and a column is a transposed row broadcast: Mosaic lowers no
+    # concatenate, cumsum or [Be] -> [Be, 1] reshape on a TPU.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_e, block_e), 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (block_e, block_e), 1)
+
+    def col(a):
+        return jnp.broadcast_to(a.reshape(1, block_e), (block_e, block_e)).T
+
     valid = key >= 0
-    prev = jnp.concatenate([jnp.full((1,), -2, jnp.int32), key[:-1]])
+    prev = jnp.max(jnp.where(rows == lanes - 1, col(key), -2), axis=0)
     new_seg = (key != prev) & valid
-    rank = jnp.cumsum(new_seg.astype(jnp.int32)) - 1
+    rank = jnp.sum(jnp.where(rows <= lanes, col(new_seg.astype(jnp.int32)),
+                             0), axis=0) - 1
     rank = jnp.where(valid, rank, -1)
 
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (block_e, block_e), 1)
-    onehot = (rank[:, None] == lanes) & valid[:, None]        # [Be, W]
+    onehot = col(rank) == lanes                # [Be, W]; rank -1 = none
+    c = col(cand)
     ident = identity_for(combine, cand.dtype)
     if combine == "min":
-        part = jnp.min(jnp.where(onehot, cand[:, None], ident), axis=0)
+        part = jnp.min(jnp.where(onehot, c, ident), axis=0)
     elif combine == "max":
-        part = jnp.max(jnp.where(onehot, cand[:, None], ident), axis=0)
+        part = jnp.max(jnp.where(onehot, c, ident), axis=0)
     elif combine == "sum":
-        part = jnp.sum(jnp.where(onehot, cand[:, None],
-                                 jnp.zeros((), cand.dtype)), axis=0)
+        part = jnp.sum(jnp.where(onehot, c, jnp.zeros((), cand.dtype)),
+                       axis=0)
     else:  # pragma: no cover
         raise ValueError(f"unknown combine {combine!r}")
-    cnt = jnp.sum(jnp.where(onehot, send[:, None].astype(jnp.int32), 0),
-                  axis=0)
-    uniq = jnp.max(jnp.where(onehot, key[:, None], -1), axis=0)
+    send_c = col(send.astype(jnp.int32))
+    cnt = jnp.sum(jnp.where(onehot, send_c, 0), axis=0)
+    uniq = jnp.max(jnp.where(onehot, col(key), -1), axis=0)
     pay_part = None
     if pay is not None:
-        win = onehot & send[:, None] & (cand[:, None] == part[None, :])
-        pay_part = jnp.max(jnp.where(win, pay[:, None], -1), axis=0)
+        win = onehot & (send_c != 0) & (c == part.reshape(1, block_e))
+        pay_part = jnp.max(jnp.where(win, col(pay), -1), axis=0)
     return part, cnt, uniq, pay_part
 
 
-def edge_relax_blocks_ref(prog, vstate, senders, gid, key, src, weight,
-                          dst_gid, block_e: int):
-    """XLA reference: the blocked combine vmapped over edge blocks.
+# Width of one level of :func:`_block_scan`.  128 = the TPU lane width.
+SCAN_BLOCK = 128
 
-    Bitwise-identical to the Pallas kernel's per-block outputs (shared
-    :func:`block_combine` body) — the engine's ``backend="xla"`` sum path.
+
+def _block_scan(comb, elems):
+    """Inclusive scan of ``elems`` (a tuple of equal-shape [..., E] arrays)
+    under the associative ``comb``, in a fixed tree order that depends
+    only on E.
+
+    Each level scans blocks of ``SCAN_BLOCK`` consecutive elements by
+    log-step shifts (Hillis-Steele, masked at block starts), scans the
+    block totals recursively, and folds each block's carry in from the
+    left.  Everything stays on the flat stream axis: slices, elementwise
+    ops and gathers only.  ``lax.associative_scan``'s odd/even recursion
+    builds one strided shape per level, and the TPU compiler's time on it
+    grows about 4x per doubling of E (104 s at E = 2^20, 409 s at 2^21,
+    v5e target); reshaping a [S, E] stream to [S, E/128, 128] blocks
+    costs about as much again (~100 s at E = 2^24.3 per cell), so the
+    blocks are never materialized.
     """
-    cand, send, pay = edge_messages(prog, vstate, senders, gid, key, src,
-                                    weight, dst_gid)
-    nb = key.shape[0] // block_e
-    blk = lambda a: a.reshape(nb, block_e)
-    if pay is None:
-        part, cnt, uniq, _ = jax.vmap(
-            lambda c, s, k: block_combine(c, s, k, None, prog.combine,
-                                          block_e)
-        )(blk(cand), blk(send), blk(key))
-        return part, cnt, uniq, None
-    part, cnt, uniq, pay_part = jax.vmap(
-        lambda c, s, k, p: block_combine(c, s, k, p, prog.combine, block_e)
-    )(blk(cand), blk(send), blk(key), blk(pay))
-    return part, cnt, uniq, pay_part
+    e = elems[0].shape[-1]
+    b = SCAN_BLOCK
+    lane = jnp.arange(e, dtype=jnp.int32) % b
+    d = 1
+    while d < min(b, e):
+        right = comb(tuple(a[..., :-d] for a in elems),
+                     tuple(a[..., d:] for a in elems))
+        keep = lane[d:] >= d
+        elems = tuple(
+            jnp.concatenate([a[..., :d], jnp.where(keep, r, a[..., d:])],
+                            axis=-1)
+            for a, r in zip(elems, right))
+        d *= 2
+    if e <= b:
+        return elems
+    nb = -(-e // b)
+    last = jnp.minimum(jnp.arange(nb, dtype=jnp.int32) * b + (b - 1), e - 1)
+    tot = _block_scan(comb, tuple(jnp.take(a, last, axis=-1) for a in elems))
+    # the carry into block j >= 1 is the inclusive scan of blocks < j
+    blk = jnp.arange(e, dtype=jnp.int32) // b
+    carry = tuple(jnp.take(t, jnp.maximum(blk - 1, 0), axis=-1) for t in tot)
+    folded = comb(carry, elems)
+    return tuple(jnp.where(blk >= 1, f, a) for f, a in zip(folded, elems))
 
 
 def stream_scan(monoid, cand, send, key, pay):
@@ -178,7 +207,7 @@ def stream_scan(monoid, cand, send, key, pay):
 
     The whole per-shard stream is globally sorted by destination key
     (``ShardedGraph.build_csr``), so every destination's messages form
-    one contiguous run.  A segmented ``lax.associative_scan`` combines
+    one contiguous run.  A segmented scan (:func:`_block_scan`) combines
     each run left-to-right in a *fixed tree order* determined only by the
     stream length — never by the lane count — which is what makes a
     lane's sum bitwise-identical to the same query run solo, and lets
@@ -204,8 +233,7 @@ def stream_scan(monoid, cand, send, key, pay):
             return (jnp.where(sb, vb, monoid.elem(va, vb)),
                     jnp.where(sb, cb, ca + cb),
                     sa | sb)
-        v, c, _ = jax.lax.associative_scan(comb, (cand, cnt, start),
-                                           axis=-1)
+        v, c, _ = _block_scan(comb, (cand, cnt, start))
         return v, c, None
 
     pay = jnp.broadcast_to(pay, cand.shape)
@@ -224,8 +252,7 @@ def stream_scan(monoid, cand, send, key, pay):
                                 jnp.where(aw, pa, jnp.maximum(pa, pb))))
         return v, c, p, sa | sb
 
-    v, c, p, _ = jax.lax.associative_scan(
-        comb, (cand, cnt, pay, start), axis=-1)
+    v, c, p, _ = _block_scan(comb, (cand, cnt, pay, start))
     return v, c, p
 
 
@@ -262,7 +289,7 @@ def delta_tables(prog, cand, send, pay, key, n_keys: int):
     (which is why the engines compact before sum-combine programs).
 
     ``cand``/``send``/``pay`` are [..., D] message streams from
-    :func:`stream_messages` over the delta slice, ``key`` [D] its
+    :func:`edge_messages` over the delta slice, ``key`` [D] its
     destination ids (``-1`` = free/tombstoned, dropped).
     """
     ids = jnp.where(key >= 0, key, n_keys).astype(jnp.int32)
@@ -348,7 +375,7 @@ def edge_relax_stream(prog, vstate, senders, gid, key, src, weight, dst_gid,
     """
     if skey is None:
         skey = key
-    cand, send, pay = stream_messages(prog, vstate, senders, gid, key, src,
+    cand, send, pay = edge_messages(prog, vstate, senders, gid, key, src,
                                       weight, dst_gid)
     return stream_combine(prog, cand, send, pay, key, skey, n_keys,
                           delta_e)
@@ -365,7 +392,8 @@ def compact_push_blocks(senders_any, push_src, block_e: int, cap: int):
     The push stream is source-sorted (``ShardedGraph.build_push_csr``), so
     a sender's out-edges are contiguous and a block is *active* iff any of
     its edges' sources is a sender.  Active block indices compact to the
-    front in ascending order (stable argsort — deterministic); fill slots
+    front in ascending order (each lands at its rank among the active
+    blocks); fill slots
     carry ``nb`` (one past the last block).  ``cap`` must bound the true
     active count — the direction selector (relax.py) guarantees it by
     picking the bucket from the measured count.
@@ -375,11 +403,14 @@ def compact_push_blocks(senders_any, push_src, block_e: int, cap: int):
     nb = push_src.shape[0] // block_e
     ok = push_src >= 0
     act = senders_any[jnp.clip(push_src, 0)] & ok            # [Eb]
-    blk = act.reshape(nb, block_e).any(axis=-1)              # [nb]
-    order = jnp.argsort(~blk, stable=True).astype(jnp.int32)
-    idx = order[:cap]
-    valid = jnp.take(blk, idx)
-    return jnp.where(valid, idx, nb), valid
+    blk = block_any(act, block_e)                            # [nb]
+    # rank-scatter compaction (no sort: a sort of nb keys costs the TPU
+    # compiler ~20 s per bucket of the push ladder at deployment size)
+    rank = jnp.cumsum(blk, dtype=jnp.int32) - 1
+    idx = jnp.full((cap,), nb, jnp.int32).at[
+        jnp.where(blk, rank, cap)].set(
+        jnp.arange(nb, dtype=jnp.int32), mode="drop")
+    return idx, idx < nb
 
 
 def push_gather(sg_push, idx, block_e: int):
@@ -388,13 +419,14 @@ def push_gather(sg_push, idx, block_e: int):
     to the last block and are neutralized by the returned ``valid`` mask.
     """
     nb = sg_push["push_src"].shape[0] // block_e
-    cap = idx.shape[0]
-    base = jnp.clip(idx, 0, nb - 1)[:, None] * block_e
-    pos = (base + jnp.arange(block_e, dtype=jnp.int32)).reshape(-1)
+    # flat [cap * block_e] positions, built without a [cap, block_e]
+    # reshape (a TPU relayout that compiles slowly at large caps)
+    j = jnp.arange(idx.shape[0] * block_e, dtype=jnp.int32)
+    blk = jnp.take(idx, j // block_e)
+    pos = jnp.clip(blk, 0, nb - 1) * block_e + j % block_e
     g = lambda a: jnp.take(a, pos, axis=-1)
     src = g(sg_push["push_src"])
-    blk_ok = jnp.repeat(idx < nb, block_e, total_repeat_length=cap * block_e)
-    valid = blk_ok & (src >= 0)
+    valid = (blk < nb) & (src >= 0)
     return {
         "src": src,
         # key carries the validity (-1 on dead positions AND fill-block
@@ -451,7 +483,7 @@ def edge_relax_push_stream(prog, vstate, senders, gid, sg_push, csr_key,
     idx, _ = compact_push_blocks(senders_any, sg_push["push_src"], block_e,
                                  cap)
     g, valid = push_gather(sg_push, idx, block_e)
-    cand, send, pay = stream_messages(prog, vstate, senders, gid, g["key"],
+    cand, send, pay = edge_messages(prog, vstate, senders, gid, g["key"],
                                       g["src"], g["weight"], g["dst_gid"])
     e = csr_key.shape[0]
     dpos = jnp.where(valid, g["pos"], e)               # fills dropped

@@ -21,8 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams as _CompilerParams
 
 __all__ = ["segment_sum_sorted"]
 
@@ -82,7 +82,7 @@ def segment_sum_sorted(
             jax.ShapeDtypeStruct((nblocks, block_e, f), jnp.float32),
             jax.ShapeDtypeStruct((nblocks, block_e), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

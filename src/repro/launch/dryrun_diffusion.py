@@ -25,7 +25,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.core.diffuse import make_spmd_diffuse  # noqa: E402
 from repro.core.programs import sssp_program      # noqa: E402
-from repro.launch.mesh import mesh_context        # noqa: E402
+from repro.launch.mesh import make_mesh           # noqa: E402
 
 
 def build_specs(scale: int, n_cells: int, edge_factor: int = 16,
@@ -76,7 +76,7 @@ def main():
     args = ap.parse_args()
 
     n_cells = 512 if args.multi_pod else 256
-    mesh = jax.make_mesh((n_cells,), ("cells",))
+    mesh = make_mesh((n_cells,), ("cells",))
     sgd, np_, ep = build_specs(args.scale, n_cells,
                                with_push=args.sweep != "pull")
     print(f"[diffusion dry-run] RMAT scale={args.scale}: "
@@ -87,9 +87,8 @@ def main():
     fn = make_spmd_diffuse(mesh, prog, sgd, axis_name="cells",
                            max_local_iters=args.max_local_iters,
                            sweep=args.sweep)
-    with mesh_context(mesh):
-        lowered = jax.jit(fn).lower(sgd)
-        compiled = lowered.compile()
+    lowered = jax.jit(fn).lower(sgd)
+    compiled = lowered.compile()
     ma = compiled.memory_analysis()
     print("memory_analysis:",
           {k: int(getattr(ma, k + "_size_in_bytes", 0))

@@ -1,30 +1,28 @@
-"""Production mesh construction (the contract used by the dry-run).
+"""Mesh construction (the contract used by the engine and the dry-run).
 
-``make_production_mesh`` is a FUNCTION so importing this module never
-touches jax device state.
+Every mesh in the repo is built here with ``AxisType.Auto`` axes.  The
+installed JAX defaults ``jax.make_mesh`` to ``Explicit`` axes, which put
+the mesh axis into the *type* of every array a shard_map returns; such
+arrays then refuse to mix with unsharded ones under ``vmap`` (the SPMD
+engine's cached states meet the logical engine's repair path at
+``commit()``) and turn ``with_sharding_constraint`` into an assert.
+
+Functions only, so importing this module never touches device state.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "mesh_context"]
+__all__ = ["make_mesh", "make_production_mesh"]
 
 
-def mesh_context(mesh):
-    """Enter ``mesh`` as the ambient mesh, across JAX versions.
-
-    Newer JAX exposes ``jax.set_mesh`` / ``jax.sharding.use_mesh``; older
-    versions (this container's 0.4.x) use the Mesh object itself as the
-    context manager.  Every call site that needs an ambient mesh goes
-    through here so the repo tracks the JAX API with one-line changes.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh  # jax.sharding.Mesh is itself a context manager
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes (see module docstring)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_shapes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,4 +30,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     the two-pod (512-chip) configuration."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

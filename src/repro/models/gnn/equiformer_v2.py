@@ -293,7 +293,6 @@ def _make_spmd_agg(cfg, mesh, data_axes, model_axis, layer_specs, n, nch,
       (lse + agg per block), so backward re-gathers x but never stores a
       full-node tensor across layers.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     h = cfg.n_heads
@@ -326,12 +325,12 @@ def _make_spmd_agg(cfg, mesh, data_axes, model_axis, layer_specs, n, nch,
         lse = shift + jnp.log(l)
         return _heads_merge(agg, h_eff, ns, x_full.shape[-1]), lse
 
-    fwd_sm = shard_map(
+    fwd_sm = jax.shard_map(
         fwd_body, mesh=mesh,
         in_specs=(layer_specs, P(None, None, mspec), espec, espec,
                   P(data_axes, None), espec),
         out_specs=(P(data_axes, None, mspec), P(data_axes, None)),
-        check_rep=False,
+        check_vma=False,
     )
 
     def bwd_body(pl, x_full, snd, rcv, vec, emask, lse, agg_l, d_agg_l):
@@ -388,13 +387,13 @@ def _make_spmd_agg(cfg, mesh, data_axes, model_axis, layer_specs, n, nch,
         dp = jax.tree_util.tree_map(lambda a, b: a.astype(b.dtype), dp, pl)
         return dp, dx, dvecs.reshape(e_l, 3)
 
-    bwd_sm = shard_map(
+    bwd_sm = jax.shard_map(
         bwd_body, mesh=mesh,
         in_specs=(layer_specs, P(None, None, mspec), espec, espec,
                   P(data_axes, None), espec, P(data_axes, None),
                   P(data_axes, None, mspec), P(data_axes, None, mspec)),
         out_specs=(layer_specs, P(None, None, mspec), P(data_axes, None)),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.custom_vjp
@@ -430,7 +429,6 @@ def _attention_agg(p, x, batch, cfg):
 
     ctx = current_context()
     if cfg.spmd_edges and ctx is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = ctx["mesh"]

@@ -166,7 +166,6 @@ def _layer(p, h, batch: GraphBatch, cfg: MACEConfig, paths, sl):
     from ...dist.sharding import current_context
     ctx = current_context()
     if cfg.spmd_edges and ctx is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh, rules = ctx["mesh"], ctx["rules"]
@@ -249,12 +248,12 @@ def _layer(p, h, batch: GraphBatch, cfg: MACEConfig, paths, sl):
         }
         espec = P(data_axes)
         pl = {k: p[k] for k in ("radial", "radial_out", "w_A")}
-        A = shard_map(
+        A = jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(pl_specs, P(None, None, mspec), espec, espec,
                       P(data_axes, None), espec),
             out_specs=P(None, None, mspec),
-            check_rep=False,
+            check_vma=False,
         )(pl, h, snd, rcv, vec, emask)
     elif nch <= 1:
         A = _a_basis_chunk(p, h, snd, rcv, vec, emask, cfg, paths, sl)

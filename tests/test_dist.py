@@ -30,7 +30,8 @@ def test_pipeline_two_stages_matches_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from repro.dist.pipeline import make_pipeline_fn, bubble_fraction
 
-        mesh = jax.make_mesh((2,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("pod",))
         def stage_fn(w, x):
             return jnp.tanh(x @ w)
 
@@ -40,9 +41,7 @@ def test_pipeline_two_stages_matches_sequential():
 
         fn = make_pipeline_fn(mesh, stage_fn, n_stages=2, n_micro=4,
                               axis="pod")
-        from repro.launch.mesh import mesh_context
-        with mesh_context(mesh):
-            ys = jax.jit(fn)(ws, xs)
+        ys = jax.jit(fn)(ws, xs)
         ref = jnp.stack([stage_fn(ws[1], stage_fn(ws[0], x)) for x in xs])
         assert np.allclose(np.asarray(ys), np.asarray(ref), atol=1e-5), (
             np.abs(np.asarray(ys) - np.asarray(ref)).max()
@@ -65,12 +64,12 @@ def test_compressed_psum_single_shard_roundtrip():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist.compressed_dp import (compressed_psum_mean,
                                               init_error_state)
 
-        mesh = jax.make_mesh((2,), ("dp",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("dp",))
         rng = np.random.default_rng(0)
         g_global = jnp.asarray(rng.normal(size=(2, 64)), jnp.float32)
 
@@ -79,11 +78,9 @@ def test_compressed_psum_single_shard_roundtrip():
                                          "dp", 2)
             return m["g"][None], e2["g"][None]
 
-        fn = shard_map(body, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                       out_specs=(P("dp"), P("dp")), check_rep=False)
-        from repro.launch.mesh import mesh_context
-        with mesh_context(mesh):
-            mean, err = jax.jit(fn)(g_global, jnp.zeros_like(g_global))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(P("dp"), P("dp")),
+                           out_specs=(P("dp"), P("dp")), check_vma=False)
+        mean, err = jax.jit(fn)(g_global, jnp.zeros_like(g_global))
         true_mean = np.asarray(g_global).mean(0)
         got = np.asarray(mean)
         # both shards agree and are close to the true mean (int8 quant)

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.dist.sharding import sharding_context
-from repro.launch.mesh import mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models.gnn.common import GraphBatch
 from repro.models.gnn import equiformer_v2 as eqv2
 from repro.models.gnn import gatedgcn, mace, meshgraphnet
@@ -115,10 +115,10 @@ def test_eqv2_chunked_and_spmd_paths_match():
     o2 = eqv2.apply(p, b, dataclasses.replace(cfg, edge_chunks=4))
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg_s = dataclasses.replace(cfg, edge_chunks=4, spmd_edges=True)
     rules = {"nodes": ("data",), "edges": ("data",), "channels": "model"}
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         with sharding_context(mesh, rules):
             o3 = jax.jit(lambda pp, bb: eqv2.apply(pp, bb, cfg_s))(p, b)
             g3 = jax.jit(
@@ -142,10 +142,10 @@ def test_mace_spmd_path_matches():
                    labels=jnp.ones(1, jnp.float32))
     p = mace.init_params(jax.random.PRNGKey(0), cfg)
     e_ref = mace.apply(p, b, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg_s = dataclasses.replace(cfg, edge_chunks=4, spmd_edges=True)
     rules = {"nodes": ("data",), "edges": ("data",), "channels": "model"}
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         with sharding_context(mesh, rules):
             e_s = jax.jit(lambda pp, bb: mace.apply(pp, bb, cfg_s))(p, b)
     np.testing.assert_allclose(np.asarray(e_ref), np.asarray(e_s),

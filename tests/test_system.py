@@ -115,16 +115,13 @@ def test_ds_termination_fires_exactly_at_quiescence():
 
 
 def test_spmd_engine_matches_logical_engine():
-    import jax
-
-    from repro.launch.mesh import mesh_context
+    from repro.launch.mesh import make_mesh
 
     src, dst, w, n = make_graph_family("erdos_renyi", 120, seed=9)
     part = build(src, dst, n, w, n_cells=1)
-    mesh = jax.make_mesh((1,), ("cells",))
+    mesh = make_mesh((1,), ("cells",))
     fn = make_spmd_diffuse(mesh, sssp_program(3), part.sg, axis_name="cells")
-    with mesh_context(mesh):
-        vs, st = fn(_sg_as_dict(part.sg))
+    vs, st = fn(_sg_as_dict(part.sg))
     ref = sssp(part, 3)
     got = np.asarray(part.to_global_layout(vs["dist"]))[: part.n_real]
     assert _dist_close(got, ref.values)
