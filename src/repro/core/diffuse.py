@@ -59,11 +59,19 @@ from .relax import (
     select_bucket,
 )
 from .termination import quiescent
+from .trace import (
+    SCOPE_APPLY,
+    SCOPE_CONTROL,
+    SCOPE_EXCHANGE,
+    SCOPE_RELAX,
+    to_host,
+)
 
 __all__ = [
     "diffuse",
     "diffuse_from",
     "exact_streams_for",
+    "initial_state",
     "DiffuseStats",
     "FRONTIER_LOG_CAP",
     "diffuse_spmd_step",
@@ -136,49 +144,54 @@ def _local_iter_shard(prog: VertexProgram, np_, s_, my_shard, sg_s, st, relax,
     monoid = prog.monoid
     ident = monoid.identity(prog.msg_dtype)
 
-    senders = _gate(prog, vstate, active, threshold)
-    if lane_live is not None:
-        senders = senders & lane_live[:, None]
-    table, cnt, pay = relax(vstate, senders, sg_s, bucket)
-    mine = (jnp.arange(s_, dtype=jnp.int32) == my_shard).reshape(
-        (s_,) + (1,) * (table.ndim - 1))
-    if member_full is None:
-        keep_local = mine
-    else:
-        member_dst = member_full.reshape(
-            (s_,) + (1,) * (table.ndim - 2) + (np_,))
-        keep_local = mine & ~member_dst
+    with jax.named_scope(SCOPE_CONTROL):
+        senders = _gate(prog, vstate, active, threshold)
+        if lane_live is not None:
+            senders = senders & lane_live[:, None]
+    with jax.named_scope(SCOPE_RELAX):
+        table, cnt, pay = relax(vstate, senders, sg_s, bucket)
+    with jax.named_scope(SCOPE_APPLY):
+        mine = (jnp.arange(s_, dtype=jnp.int32) == my_shard).reshape(
+            (s_,) + (1,) * (table.ndim - 1))
+        if member_full is None:
+            keep_local = mine
+        else:
+            member_dst = member_full.reshape(
+                (s_,) + (1,) * (table.ndim - 2) + (np_,))
+            keep_local = mine & ~member_dst
 
-    inbox = jnp.take(table, my_shard, axis=0)
-    has_local = jnp.take(cnt, my_shard, axis=0) > 0
-    pay_in = jnp.take(pay, my_shard, axis=0) if prog.with_payload else None
-    if member_full is not None:
-        member_row = jnp.take(member_full, my_shard, axis=0)    # [Np]
-        has_local = has_local & ~member_row
-        inbox = jnp.where(member_row, ident, inbox)
+        inbox = jnp.take(table, my_shard, axis=0)
+        has_local = jnp.take(cnt, my_shard, axis=0) > 0
+        pay_in = (jnp.take(pay, my_shard, axis=0) if prog.with_payload
+                  else None)
+        if member_full is not None:
+            member_row = jnp.take(member_full, my_shard, axis=0)   # [Np]
+            has_local = has_local & ~member_row
+            inbox = jnp.where(member_row, ident, inbox)
+            if prog.with_payload:
+                pay_in = jnp.where(member_row, -1, pay_in)
+
+        contrib = jnp.where(keep_local, ident, table)
+        contrib_has = (cnt > 0) & ~keep_local
         if prog.with_payload:
-            pay_in = jnp.where(member_row, -1, pay_in)
+            pay_contrib = jnp.where(keep_local, -1, pay)
+            take_new = contrib_has & monoid.improves(contrib, outbox)
+            outbox_pay = jnp.where(take_new, pay_contrib, outbox_pay)
+        outbox = monoid.merge(outbox, contrib, contrib_has)
+        outbox_has = outbox_has | contrib_has
 
-    contrib = jnp.where(keep_local, ident, table)
-    contrib_has = (cnt > 0) & ~keep_local
-    if prog.with_payload:
-        pay_contrib = jnp.where(keep_local, -1, pay)
-        take_new = contrib_has & monoid.improves(contrib, outbox)
-        outbox_pay = jnp.where(take_new, pay_contrib, outbox_pay)
-    outbox = monoid.merge(outbox, contrib, contrib_has)
-    outbox_has = outbox_has | contrib_has
+        vstate = prog.on_send(vstate, senders)
+        vstate, activated = prog.receive(
+            vstate, inbox, has_local, pay_in, sg_s["node_ok"]
+        )
+        activated = activated | (active & ~senders)   # withheld stay active
 
-    vstate = prog.on_send(vstate, senders)
-    vstate, activated = prog.receive(
-        vstate, inbox, has_local, pay_in, sg_s["node_ok"]
-    )
-    activated = activated | (active & ~senders)   # withheld stay active
-
-    n_send = jnp.sum(cnt)                          # sending edges (actions)
-    counts = {
-        "actions": n_send,
-        "remote": n_send - jnp.sum(jnp.where(keep_local, cnt, 0)),
-    }
+    with jax.named_scope(SCOPE_CONTROL):
+        n_send = jnp.sum(cnt)                      # sending edges (actions)
+        counts = {
+            "actions": n_send,
+            "remote": n_send - jnp.sum(jnp.where(keep_local, cnt, 0)),
+        }
     return (vstate, activated, outbox, outbox_has, outbox_pay), counts
 
 
@@ -371,6 +384,7 @@ def _run_rounds(sg: ShardedGraph, prog: VertexProgram, vstate0, active0,
         counts = active_push_blocks(gated, sgd["push_src"], sg.csr_block)
         return select_bucket(counts, nb, sweep, push_threshold)
 
+    @jax.named_scope(SCOPE_CONTROL)
     def round_cond(c):
         st, stats = c
         _, active, _, outbox_has, _ = st
@@ -381,31 +395,35 @@ def _run_rounds(sg: ShardedGraph, prog: VertexProgram, vstate0, active0,
 
     def round_body(c):
         st, stats = c
-        if use_gate:
-            # bucket threshold: min active priority + delta, per round —
-            # computed per lane so a gated laned run reproduces each
-            # single-query bucket sequence exactly
-            prio = jax.vmap(prog.priority)(st[0])
-            masked = jnp.where(st[1], prio, jnp.inf)
-            if L:
-                thr = jnp.min(masked, axis=(0, masked.ndim - 1))[:, None] + delta
+        with jax.named_scope(SCOPE_CONTROL):
+            if use_gate:
+                # bucket threshold: min active priority + delta, per
+                # round — computed per lane so a gated laned run
+                # reproduces each single-query bucket sequence exactly
+                prio = jax.vmap(prog.priority)(st[0])
+                masked = jnp.where(st[1], prio, jnp.inf)
+                if L:
+                    thr = jnp.min(masked, axis=(0, masked.ndim - 1))[
+                        :, None] + delta
+                else:
+                    thr = jnp.min(masked) + delta
             else:
-                thr = jnp.min(masked) + delta
-        else:
-            thr = jnp.inf
-        # per-lane quiescence: converged lanes stop generating messages
-        lane_live = jnp.any(st[1], axis=(0, st[1].ndim - 1)) if L else None
+                thr = jnp.inf
+            # per-lane quiescence: converged lanes stop generating messages
+            lane_live = (jnp.any(st[1], axis=(0, st[1].ndim - 1)) if L
+                         else None)
 
-        # round-start introspection: frontier size here; the direction is
-        # logged by the first local sub-iteration from the bucket it
-        # actually dispatches (the frontier may grow mid-round; only the
-        # opening choice is logged — push_iters counts the rest)
-        li = jnp.minimum(stats.rounds, FRONTIER_LOG_CAP - 1)
-        stats = stats._replace(
-            frontier_log=stats.frontier_log.at[li].set(
-                jnp.sum(st[1].astype(jnp.int32))),
-        )
+            # round-start introspection: frontier size here; the direction
+            # is logged by the first local sub-iteration from the bucket it
+            # actually dispatches (the frontier may grow mid-round; only
+            # the opening choice is logged — push_iters counts the rest)
+            li = jnp.minimum(stats.rounds, FRONTIER_LOG_CAP - 1)
+            stats = stats._replace(
+                frontier_log=stats.frontier_log.at[li].set(
+                    jnp.sum(st[1].astype(jnp.int32))),
+            )
 
+        @jax.named_scope(SCOPE_CONTROL)
         def local_cond(c2):
             st2, stats2, liters = c2
             gated = jax.vmap(lambda vs, a: _gate(prog, vs, a,
@@ -415,12 +433,14 @@ def _run_rounds(sg: ShardedGraph, prog: VertexProgram, vstate0, active0,
 
         def local_body(c2):
             st2, stats2, liters = c2
-            if sweep != "pull":
-                bucket = _bucket_of(st2[0], st2[1],
-                                    thr if use_gate else None, lane_live)
-                is_push = jnp.where(bucket < n_caps, 1, 0).astype(jnp.int32)
-            else:
-                bucket, is_push = None, jnp.zeros((), jnp.int32)
+            with jax.named_scope(SCOPE_CONTROL):
+                if sweep != "pull":
+                    bucket = _bucket_of(st2[0], st2[1],
+                                        thr if use_gate else None, lane_live)
+                    is_push = jnp.where(bucket < n_caps, 1, 0).astype(
+                        jnp.int32)
+                else:
+                    bucket, is_push = None, jnp.zeros((), jnp.int32)
             local_iter = jax.vmap(
                 lambda i, g, s: _local_iter_shard(
                     prog, Np, S, i, g, s, relax,
@@ -430,57 +450,62 @@ def _run_rounds(sg: ShardedGraph, prog: VertexProgram, vstate0, active0,
                 in_axes=(0, 0, 0),
             )
             st2, counts = local_iter(shard_ids, sgd, st2)
-            stats2 = stats2._replace(
-                local_iters=stats2.local_iters + 1,
-                actions=stats2.actions + jnp.sum(counts["actions"]),
-                remote_actions=stats2.remote_actions
-                + jnp.sum(counts["remote"]),
-                max_frontier=jnp.maximum(
-                    stats2.max_frontier, jnp.sum(st2[1].astype(jnp.int32))
-                ),
-                push_iters=stats2.push_iters + is_push,
-                dir_log=stats2.dir_log.at[li].set(
-                    jnp.where(liters == 0, is_push, stats2.dir_log[li])),
-            )
-            return st2, stats2, liters + 1
+            with jax.named_scope(SCOPE_CONTROL):
+                stats2 = stats2._replace(
+                    local_iters=stats2.local_iters + 1,
+                    actions=stats2.actions + jnp.sum(counts["actions"]),
+                    remote_actions=stats2.remote_actions
+                    + jnp.sum(counts["remote"]),
+                    max_frontier=jnp.maximum(
+                        stats2.max_frontier,
+                        jnp.sum(st2[1].astype(jnp.int32))),
+                    push_iters=stats2.push_iters + is_push,
+                    dir_log=stats2.dir_log.at[li].set(
+                        jnp.where(liters == 0, is_push, stats2.dir_log[li])),
+                )
+                liters = liters + 1
+            return st2, stats2, liters
 
         st, stats, _ = lax.while_loop(
             local_cond, local_body, (st, stats, jnp.zeros((), jnp.int32))
         )
         vstate, active, outbox, outbox_has, outbox_pay = st
         # ---- exchange: deliver every outbox to its destination cell ----
-        n_ops = jnp.sum(outbox_has.astype(jnp.int32))
-        inbox_all = monoid.reduce_rows(outbox, outbox_has, axis=0)
-        has_all = outbox_has.any(axis=0)
-        pay_all = None
-        if prog.with_payload:
-            src_idx = monoid.argbest(outbox, axis=0)
-            pay_all = jnp.take_along_axis(outbox_pay, src_idx[None], axis=0)[0]
-        if rmem is not None:
-            # replica merge, folded into the exchange: member partials
-            # combine through the monoid and the merged message lands on
-            # every member slot before receive
-            inbox_all, has_all, pay_all = _merge_replicas(
-                monoid, prog.with_payload, ident, rmem, S, Np,
-                inbox_all, has_all, pay_all)
-        recv = jax.vmap(
-            lambda vs, ib, hs, pl, nok: prog.receive(vs, ib, hs, pl, nok)
-        )
-        vstate, activated = recv(vstate, inbox_all, has_all, pay_all,
-                                 sgd["node_ok"])
-        active = active | activated
-        outbox = jnp.full_like(outbox, ident)
-        outbox_has = jnp.zeros_like(outbox_has)
-        if prog.with_payload:
-            outbox_pay = jnp.full_like(outbox_pay, -1)
-        stats = stats._replace(
-            rounds=stats.rounds + 1,
-            operons_sent=stats.operons_sent + n_ops,
-            operons_delivered=stats.operons_delivered + n_ops,
-            max_frontier=jnp.maximum(
-                stats.max_frontier, jnp.sum(active.astype(jnp.int32))
-            ),
-        )
+        with jax.named_scope(SCOPE_EXCHANGE):
+            n_ops = jnp.sum(outbox_has.astype(jnp.int32))
+            inbox_all = monoid.reduce_rows(outbox, outbox_has, axis=0)
+            has_all = outbox_has.any(axis=0)
+            pay_all = None
+            if prog.with_payload:
+                src_idx = monoid.argbest(outbox, axis=0)
+                pay_all = jnp.take_along_axis(outbox_pay, src_idx[None],
+                                              axis=0)[0]
+            if rmem is not None:
+                # replica merge, folded into the exchange: member partials
+                # combine through the monoid and the merged message lands
+                # on every member slot before receive
+                inbox_all, has_all, pay_all = _merge_replicas(
+                    monoid, prog.with_payload, ident, rmem, S, Np,
+                    inbox_all, has_all, pay_all)
+            recv = jax.vmap(
+                lambda vs, ib, hs, pl, nok: prog.receive(vs, ib, hs, pl, nok)
+            )
+            vstate, activated = recv(vstate, inbox_all, has_all, pay_all,
+                                     sgd["node_ok"])
+            active = active | activated
+            outbox = jnp.full_like(outbox, ident)
+            outbox_has = jnp.zeros_like(outbox_has)
+            if prog.with_payload:
+                outbox_pay = jnp.full_like(outbox_pay, -1)
+        with jax.named_scope(SCOPE_CONTROL):
+            stats = stats._replace(
+                rounds=stats.rounds + 1,
+                operons_sent=stats.operons_sent + n_ops,
+                operons_delivered=stats.operons_delivered + n_ops,
+                max_frontier=jnp.maximum(
+                    stats.max_frontier, jnp.sum(active.astype(jnp.int32))
+                ),
+            )
         return (vstate, active, outbox, outbox_has, outbox_pay), stats
 
     st0 = (vstate0, active0, outbox0, has0, pay0)
@@ -488,9 +513,10 @@ def _run_rounds(sg: ShardedGraph, prog: VertexProgram, vstate0, active0,
     # budget watchdog: the loop exits on quiescence OR rounds == max_rounds;
     # re-evaluating the predicate on the final state tells the two apart
     _, active_f, _, outbox_has_f, _ = st
-    stats = stats._replace(converged=quiescent(
-        jnp.sum(active_f.astype(jnp.int32)),
-        jnp.sum(outbox_has_f.astype(jnp.int32))))
+    with jax.named_scope(SCOPE_CONTROL):
+        stats = stats._replace(converged=quiescent(
+            jnp.sum(active_f.astype(jnp.int32)),
+            jnp.sum(outbox_has_f.astype(jnp.int32))))
     return st[0], stats
 
 
@@ -515,10 +541,21 @@ def exact_streams_for(sg: ShardedGraph, prog: VertexProgram) -> ShardedGraph:
         return sg
     # intentional O(cells) policy read: device_get (not int()/.item()) so
     # a warm query stays legal under jax.transfer_guard("disallow")
-    dc = jax.device_get(sg.delta_count)  # analysis: allow(host-sync): per-query policy counters, guard-legal
-    tc = jax.device_get(sg.tomb_count)   # analysis: allow(host-sync): per-query policy counters, guard-legal
+    dc = to_host(sg.delta_count, "exact_streams")
+    tc = to_host(sg.tomb_count, "exact_streams")
     dirty = (dc.max(initial=0) + tc.max(initial=0)) > 0
     return sg.with_csr() if dirty else sg
+
+
+def initial_state(sg: ShardedGraph, prog: VertexProgram):
+    """The program's ``(vstate, active)`` on ``sg``, computed eagerly.
+
+    Init runs concretely (not traced), so its per-query scalar constants
+    (e.g. the source id) upload h2d here; that O(1) upload is legal under
+    the sanitizer, whose contract guards d2h syncs and retraces — the d2h
+    direction of any ambient guard stays in force."""
+    with jax.transfer_guard_host_to_device("allow"):
+        return prog.init(logical_view(sg))
 
 
 def diffuse(
@@ -551,12 +588,7 @@ def diffuse(
     """
     sg = part.sg if isinstance(part, Partitioned) else part
     sg = exact_streams_for(sg, prog)
-    # init runs concretely (not traced), so its per-query scalar
-    # constants (e.g. the source id) upload h2d here; that O(1) upload
-    # is legal under the sanitizer, whose contract guards d2h syncs and
-    # retraces — leave the d2h direction of any ambient guard in force.
-    with jax.transfer_guard_host_to_device("allow"):
-        vstate0, active0 = prog.init(logical_view(sg))
+    vstate0, active0 = initial_state(sg, prog)
     return _run_rounds(sg, prog, vstate0, active0, max_local_iters,
                        max_rounds, delta, backend, sweep, push_threshold)
 
@@ -678,128 +710,143 @@ def diffuse_spmd_step(prog: VertexProgram, axis_name: str, n_shards: int,
             counts = active_push_blocks(act, sg_s["push_src"], block_e)
             return select_bucket(counts, nb, sweep, push_threshold)
 
+        @jax.named_scope(SCOPE_CONTROL)
         def round_cond(c):
             _, _, global_live, stats = c
             return (global_live > 0) & (stats.rounds < max_rounds)
 
         def round_body(c):
             st, _, global_live, stats = c
-            if L:
-                # per-lane global quiescence: psum'd lane frontiers mask
-                # converged lanes out of message generation
-                lane_live = lax.psum(
-                    jnp.sum(st[1].astype(jnp.int32), axis=-1), axis_name
-                ) > 0
-            else:
-                lane_live = None
+            with jax.named_scope(SCOPE_CONTROL):
+                if L:
+                    # per-lane global quiescence: psum'd lane frontiers
+                    # mask converged lanes out of message generation
+                    lane_live = lax.psum(
+                        jnp.sum(st[1].astype(jnp.int32), axis=-1), axis_name
+                    ) > 0
+                else:
+                    lane_live = None
 
-            # round-start introspection: the psum'd frontier is already in
-            # hand (replicated); the direction is this device's opening
-            # pick, logged by the first local sub-iteration and pmax'd
-            # into the log at the end
-            li = jnp.minimum(stats.rounds, FRONTIER_LOG_CAP - 1)
-            stats = stats._replace(
-                frontier_log=stats.frontier_log.at[li].set(
-                    global_live.astype(jnp.int32)),
-            )
+                # round-start introspection: the psum'd frontier is
+                # already in hand (replicated); the direction is this
+                # device's opening pick, logged by the first local
+                # sub-iteration and pmax'd into the log at the end
+                li = jnp.minimum(stats.rounds, FRONTIER_LOG_CAP - 1)
+                stats = stats._replace(
+                    frontier_log=stats.frontier_log.at[li].set(
+                        global_live.astype(jnp.int32)),
+                )
 
+            @jax.named_scope(SCOPE_CONTROL)
             def local_cond(c2):
                 st2, stats2, liters = c2
                 return jnp.any(st2[1]) & (liters < max_local_iters)
 
             def local_body(c2):
                 st2, stats2, liters = c2
-                if sweep != "pull":
-                    act = (st2[1] if lane_live is None
-                           else st2[1] & lane_live[:, None])
-                    bucket = _bucket_of(act)
-                    is_push = jnp.where(bucket < n_caps, 1, 0).astype(
-                        jnp.int32)
-                else:
-                    bucket, is_push = None, jnp.zeros((), jnp.int32)
+                with jax.named_scope(SCOPE_CONTROL):
+                    if sweep != "pull":
+                        act = (st2[1] if lane_live is None
+                               else st2[1] & lane_live[:, None])
+                        bucket = _bucket_of(act)
+                        is_push = jnp.where(bucket < n_caps, 1, 0).astype(
+                            jnp.int32)
+                    else:
+                        bucket, is_push = None, jnp.zeros((), jnp.int32)
                 st2, counts = _local_iter_shard(prog, Np, S, my_shard, sg_s,
                                                 st2, relax, None, lane_live,
                                                 bucket,
                                                 member_full=member_mask)
-                stats2 = stats2._replace(
-                    local_iters=stats2.local_iters + 1,
-                    actions=stats2.actions + counts["actions"],
-                    remote_actions=stats2.remote_actions + counts["remote"],
-                    push_iters=stats2.push_iters + is_push,
-                    dir_log=stats2.dir_log.at[li].set(
-                        jnp.where(liters == 0, is_push,
-                                  stats2.dir_log[li])),
-                )
-                return st2, stats2, liters + 1
+                with jax.named_scope(SCOPE_CONTROL):
+                    stats2 = stats2._replace(
+                        local_iters=stats2.local_iters + 1,
+                        actions=stats2.actions + counts["actions"],
+                        remote_actions=stats2.remote_actions
+                        + counts["remote"],
+                        push_iters=stats2.push_iters + is_push,
+                        dir_log=stats2.dir_log.at[li].set(
+                            jnp.where(liters == 0, is_push,
+                                      stats2.dir_log[li])),
+                    )
+                    liters = liters + 1
+                return st2, stats2, liters
 
             st, stats, _ = lax.while_loop(
                 local_cond, local_body, (st, stats, jnp.zeros((), jnp.int32))
             )
             vstate, active, outbox, outbox_has, outbox_pay = st
-            n_ops = jnp.sum(outbox_has.astype(jnp.int32))
-            # exchange: row t of my outbox goes to device t
-            rec = lax.all_to_all(outbox, axis_name, split_axis=0,
-                                 concat_axis=0, tiled=True)
-            rec_has = lax.all_to_all(
-                outbox_has.astype(jnp.int8), axis_name, split_axis=0,
-                concat_axis=0, tiled=True,
-            ) > 0
-            inbox = monoid.reduce_rows(rec, rec_has, axis=0)
-            has = rec_has.any(axis=0)
-            pay = None
-            if prog.with_payload:
-                rec_pay = lax.all_to_all(outbox_pay, axis_name, split_axis=0,
-                                         concat_axis=0, tiled=True)
-                idx = monoid.argbest(rec, axis=0)
-                pay = jnp.take_along_axis(rec_pay, idx[None], axis=0)[0]
-                outbox_pay = jnp.full_like(outbox_pay, -1)
-            if rmem is not None:
-                # replica merge on the gathered [S, ...] rows — the exact
-                # computation the logical engine runs, then re-slice this
-                # device's row, so both engines merge bit-identically
-                ib = lax.all_gather(inbox, axis_name)
-                hs = lax.all_gather(has, axis_name)
-                pa = (lax.all_gather(pay, axis_name)
-                      if prog.with_payload else None)
-                ib, hs, pa = _merge_replicas(
-                    monoid, prog.with_payload, ident_f(), rmem, S, Np,
-                    ib, hs, pa)
-                inbox = jnp.take(ib, my_shard, axis=0)
-                has = jnp.take(hs, my_shard, axis=0)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                n_ops = jnp.sum(outbox_has.astype(jnp.int32))
+                # exchange: row t of my outbox goes to device t
+                rec = lax.all_to_all(outbox, axis_name, split_axis=0,
+                                     concat_axis=0, tiled=True)
+                rec_has = lax.all_to_all(
+                    outbox_has.astype(jnp.int8), axis_name, split_axis=0,
+                    concat_axis=0, tiled=True,
+                ) > 0
+                inbox = monoid.reduce_rows(rec, rec_has, axis=0)
+                has = rec_has.any(axis=0)
+                pay = None
                 if prog.with_payload:
-                    pay = jnp.take(pa, my_shard, axis=0)
-            vstate, activated = prog.receive(vstate, inbox, has, pay,
-                                             sg_s["node_ok"])
-            active = active | activated
-            outbox = jnp.full((S,) + lane + (Np,), ident_f(), prog.msg_dtype)
-            outbox_has = jnp.zeros((S,) + lane + (Np,), bool)
-            live = lax.psum(jnp.sum(active.astype(jnp.int32)), axis_name)
-            delivered = lax.psum(n_ops, axis_name)
-            stats = stats._replace(
-                rounds=stats.rounds + 1,
-                operons_sent=stats.operons_sent + n_ops,
-                operons_delivered=stats.operons_delivered + delivered,
-            )
-            return (vstate, active, outbox, outbox_has, outbox_pay), None, live, stats
+                    rec_pay = lax.all_to_all(outbox_pay, axis_name,
+                                             split_axis=0, concat_axis=0,
+                                             tiled=True)
+                    idx = monoid.argbest(rec, axis=0)
+                    pay = jnp.take_along_axis(rec_pay, idx[None], axis=0)[0]
+                    outbox_pay = jnp.full_like(outbox_pay, -1)
+                if rmem is not None:
+                    # replica merge on the gathered [S, ...] rows — the
+                    # exact computation the logical engine runs, then
+                    # re-slice this device's row, so both engines merge
+                    # bit-identically
+                    ib = lax.all_gather(inbox, axis_name)
+                    hs = lax.all_gather(has, axis_name)
+                    pa = (lax.all_gather(pay, axis_name)
+                          if prog.with_payload else None)
+                    ib, hs, pa = _merge_replicas(
+                        monoid, prog.with_payload, ident_f(), rmem, S, Np,
+                        ib, hs, pa)
+                    inbox = jnp.take(ib, my_shard, axis=0)
+                    has = jnp.take(hs, my_shard, axis=0)
+                    if prog.with_payload:
+                        pay = jnp.take(pa, my_shard, axis=0)
+                vstate, activated = prog.receive(vstate, inbox, has, pay,
+                                                 sg_s["node_ok"])
+                active = active | activated
+                outbox = jnp.full((S,) + lane + (Np,), ident_f(),
+                                  prog.msg_dtype)
+                outbox_has = jnp.zeros((S,) + lane + (Np,), bool)
+            with jax.named_scope(SCOPE_CONTROL):
+                live = lax.psum(jnp.sum(active.astype(jnp.int32)), axis_name)
+                delivered = lax.psum(n_ops, axis_name)
+                stats = stats._replace(
+                    rounds=stats.rounds + 1,
+                    operons_sent=stats.operons_sent + n_ops,
+                    operons_delivered=stats.operons_delivered + delivered,
+                )
+            return ((vstate, active, outbox, outbox_has, outbox_pay), None,
+                    live, stats)
 
-        live0 = lax.psum(jnp.sum(active.astype(jnp.int32)), axis_name)
+        with jax.named_scope(SCOPE_CONTROL):
+            live0 = lax.psum(jnp.sum(active.astype(jnp.int32)), axis_name)
         st0 = (vstate, active, outbox, outbox_has, outbox_pay)
         st, _, live_f, stats = lax.while_loop(
             round_cond, round_body, (st0, None, live0, stats)
         )
         vfinal = jax.tree_util.tree_map(lambda a: a[None], st[0])
-        stats = stats._replace(
-            # live_f is already a psum — replicated, so every device
-            # reports the same budget-vs-quiescence verdict
-            converged=(live_f == 0),
-            actions=lax.psum(stats.actions, axis_name),
-            remote_actions=lax.psum(stats.remote_actions, axis_name),
-            operons_sent=lax.psum(stats.operons_sent, axis_name),
-            local_iters=lax.pmax(stats.local_iters, axis_name),
-            max_frontier=lax.pmax(stats.max_frontier, axis_name),
-            push_iters=lax.pmax(stats.push_iters, axis_name),
-            dir_log=lax.pmax(stats.dir_log, axis_name),
-        )
+        with jax.named_scope(SCOPE_CONTROL):
+            stats = stats._replace(
+                # live_f is already a psum — replicated, so every device
+                # reports the same budget-vs-quiescence verdict
+                converged=(live_f == 0),
+                actions=lax.psum(stats.actions, axis_name),
+                remote_actions=lax.psum(stats.remote_actions, axis_name),
+                operons_sent=lax.psum(stats.operons_sent, axis_name),
+                local_iters=lax.pmax(stats.local_iters, axis_name),
+                max_frontier=lax.pmax(stats.max_frontier, axis_name),
+                push_iters=lax.pmax(stats.push_iters, axis_name),
+                dir_log=lax.pmax(stats.dir_log, axis_name),
+            )
         return vfinal, stats
 
     return per_device

@@ -69,12 +69,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import chaos
+from . import trace
 from .diffuse import (
     DiffuseStats,
     _sg_as_dict,
     diffuse,
     diffuse_from,
     exact_streams_for,
+    initial_state,
     logical_view,
     make_spmd_diffuse,
 )
@@ -307,12 +309,13 @@ class DiffusionSession:
         """Size of the global id space (capacity + dynamically added)."""
         if self._ns is not None:
             return int(self._ns.owner.shape[0])
-        return int(np.asarray(self.part.owner).shape[0])
+        return int(self.part.owner.shape[0])
 
     def _layout(self):
         if self._ns is not None:
             return self._ns.owner, self._ns.local
-        return np.asarray(self.part.owner), np.asarray(self.part.local)
+        return (trace.to_host(self.part.owner, "layout"),
+                trace.to_host(self.part.local, "layout"))
 
     def to_global(self, values) -> np.ndarray:
         """[S, Np] shard layout -> [n_ids] gid order (via the name server,
@@ -322,13 +325,13 @@ class DiffusionSession:
         slot mapping and may alias a live vertex's value — mask with
         :meth:`live_ids` when iterating the full id space."""
         owner, local = self._layout()
-        return np.asarray(values)[owner, local]
+        return trace.to_host(values, "to_global")[owner, local]
 
     def live_ids(self) -> np.ndarray:
         """[n_ids] bool: ids currently naming a live vertex."""
         owner, local = self._layout()
-        ok = np.asarray(self.sg.node_ok)[owner, local]
-        gid = np.asarray(self.sg.gid)[owner, local]
+        ok = trace.to_host(self.sg.node_ok, "live_ids")[owner, local]
+        gid = trace.to_host(self.sg.gid, "live_ids")[owner, local]
         return ok & (gid == np.arange(owner.shape[0]))
 
     def edge_list(self):
@@ -450,7 +453,17 @@ class DiffusionSession:
         the returned values against the program's Field schema — NaN and
         out-of-domain values on live vertices raise
         :class:`ValidationError` (DESIGN.md §2.13).
+
+        Each call is the host span ``repro.query``; a diffusion inside it
+        runs the stages ``prepare``, ``dispatch``, ``budget`` and
+        ``result`` (:mod:`~.trace`).
         """
+        with trace.stage(trace.QUERY):
+            return self._query(prog, engine, backend, sweep, refresh,
+                               value_key, delta, validate, kwargs)
+
+    def _query(self, prog, engine, backend, sweep, refresh, value_key,
+               delta, validate, kwargs):
         engine = engine or self.engine
         explicit_backend = backend
         backend = backend or self.backend
@@ -518,12 +531,13 @@ class DiffusionSession:
         if not refresh:
             hit = self._cache_get(key)
             if hit is not None:
-                res = self._result(hit)
-                # re-validate on every serve: a poisoned cached state
-                # (chaos.poison_vstate, a bad repair) is caught at read
-                # time, not just at compute time
-                self._maybe_validate(hit, res, validate,
-                                     f"query {name!r} (cached)")
+                with trace.stage(trace.QUERY_RESULT):
+                    res = self._result(hit)
+                    # re-validate on every serve: a poisoned cached state
+                    # (chaos.poison_vstate, a bad repair) is caught at
+                    # read time, not just at compute time
+                    self._maybe_validate(hit, res, validate,
+                                         f"query {name!r} (cached)")
                 return res
 
         if engine == "event":
@@ -548,17 +562,22 @@ class DiffusionSession:
             return Result(values=values, stats=st,
                           extra={"live": self.live_ids()})
 
-        program = adhoc if adhoc is not None else spec.factory(**kwargs)
+        with trace.stage(trace.QUERY_PREPARE):
+            program = adhoc if adhoc is not None else spec.factory(**kwargs)
+            run = self._prepare_diffusion(program, engine, backend, delta,
+                                          sweep)
+        with trace.stage(trace.QUERY_DISPATCH):
+            vstate, stats = run()
         vk = value_key or spec.value_key
-        vstate, stats = self._run_diffusion(program, engine, backend, delta,
-                                            sweep)
         entry = _Entry(spec, program, vk, dict(kwargs), vstate, stats,
                        engine, backend=backend, delta=delta,
                        sweep=explicit_sweep)
         self._cache_put(key, entry)
-        self._enforce_budget(stats, f"query {name!r}")
-        res = self._result(entry)
-        self._maybe_validate(entry, res, validate, f"query {name!r}")
+        with trace.stage(trace.QUERY_BUDGET):
+            self._enforce_budget(stats, f"query {name!r}")
+        with trace.stage(trace.QUERY_RESULT):
+            res = self._result(entry)
+            self._maybe_validate(entry, res, validate, f"query {name!r}")
         return res
 
     def _compact_for(self, program: VertexProgram | None):
@@ -571,15 +590,42 @@ class DiffusionSession:
         if program is not None:
             self.part.sg = exact_streams_for(self.sg, program)
 
-    def _run_diffusion(self, program: VertexProgram, engine: str,
-                       backend: str, delta, sweep: str = "pull"):
+    def _prepare_diffusion(self, program: VertexProgram, engine: str,
+                           backend: str, delta, sweep: str = "pull"):
+        """Compact, build the initial state (sharded) or the shard_map
+        function (spmd), and return the device call that runs the
+        diffusion: ``() -> (vstate, stats)``."""
         self._compact_for(program)
+        sg = self.sg
         if engine == "sharded":
-            return diffuse(
-                self.sg, program, max_local_iters=self.max_local_iters,
+            vstate0, active0 = initial_state(sg, program)
+            return lambda: diffuse_from(
+                sg, program, vstate0, active0,
+                max_local_iters=self.max_local_iters,
                 max_rounds=self.max_rounds, delta=delta, backend=backend,
                 sweep=sweep)
-        return self._run_spmd(program, backend, sweep)
+        S = self.n_cells
+        if len(jax.devices()) < S:
+            raise RuntimeError(
+                f"engine='spmd' needs >= {S} devices (one per compute "
+                f"cell); this process has {len(jax.devices())}. Set "
+                f"XLA_FLAGS=--xla_force_host_platform_device_count={S} "
+                f"before importing jax, or use engine='sharded'.")
+        # the per-device fn traces prog.init *inside* shard_map, so the
+        # cache key needs the init identity on top of the program's
+        # (init-excluding) structural equality — see VertexProgram.__eq__
+        fkey = (program, _fn_key(program.init), S, backend, sweep)
+        if fkey not in self._spmd_fns:
+            from ..launch.mesh import make_mesh
+
+            mesh = make_mesh((S,), ("cells",))
+            self._spmd_fns[fkey] = make_spmd_diffuse(
+                mesh, program, sg, axis_name="cells",
+                max_local_iters=self.max_local_iters,
+                max_rounds=self.max_rounds, backend=backend, sweep=sweep)
+        fn = self._spmd_fns[fkey]
+        sgd = _sg_as_dict(sg, with_push=sweep != "pull")
+        return lambda: fn(sgd)
 
     def _query_lanes(self, spec: ProgramSpec, name: str, lane_vals: list,
                      kwargs: dict, engine: str, backend: str,
@@ -600,7 +646,8 @@ class DiffusionSession:
         keys = [self._key(name, engine, kw, backend, delta, sweep)
                 for kw in per_lane]
         if not refresh and all(k in self._cache for k in keys):
-            return [self._result(self._cache_get(k)) for k in keys]
+            with trace.stage(trace.QUERY_RESULT):
+                return [self._result(self._cache_get(k)) for k in keys]
 
         if engine == "event":
             # the host oracle is message-at-a-time; lanes degrade to a loop
@@ -608,30 +655,35 @@ class DiffusionSession:
                                value_key=value_key, **kw)
                     for kw in per_lane]
 
-        progs = tuple(spec.factory(**kw) for kw in per_lane)
-        laned = make_laned(progs)
-        vstate, stats = self._run_diffusion(laned, engine, backend, delta,
-                                            sweep)
-        self._enforce_budget(stats, f"query {name!r} "
-                                    f"({len(lane_vals)} lanes)")
+        with trace.stage(trace.QUERY_PREPARE):
+            progs = tuple(spec.factory(**kw) for kw in per_lane)
+            laned = make_laned(progs)
+            run = self._prepare_diffusion(laned, engine, backend, delta,
+                                          sweep)
+        with trace.stage(trace.QUERY_DISPATCH):
+            vstate, stats = run()
+        with trace.stage(trace.QUERY_BUDGET):
+            self._enforce_budget(stats, f"query {name!r} "
+                                        f"({len(lane_vals)} lanes)")
 
         vk = value_key or spec.value_key
         results = []
-        for i, (kw, key) in enumerate(zip(per_lane, keys)):
-            # slicing lane i uploads the literal index — an O(1) h2d per
-            # lane, legal under the sanitizer (which guards d2h syncs
-            # and retraces); keep the d2h direction guarded
-            with jax.transfer_guard_host_to_device("allow"):
-                lane_state = jax.tree_util.tree_map(lambda a: a[:, i],
-                                                    vstate)
-            entry = _Entry(spec, progs[i], vk, kw, lane_state,
-                           stats, engine, backend=backend, delta=delta,
-                           sweep=explicit_sweep)
-            self._cache_put(key, entry)
-            res = self._result(entry)
-            self._maybe_validate(entry, res, validate,
-                                 f"query {name!r} lane {i}")
-            results.append(res)
+        with trace.stage(trace.QUERY_RESULT):
+            for i, (kw, key) in enumerate(zip(per_lane, keys)):
+                # slicing lane i uploads the literal index — an O(1) h2d
+                # per lane, legal under the sanitizer (which guards d2h
+                # syncs and retraces); keep the d2h direction guarded
+                with jax.transfer_guard_host_to_device("allow"):
+                    lane_state = jax.tree_util.tree_map(lambda a: a[:, i],
+                                                        vstate)
+                entry = _Entry(spec, progs[i], vk, kw, lane_state,
+                               stats, engine, backend=backend, delta=delta,
+                               sweep=explicit_sweep)
+                self._cache_put(key, entry)
+                res = self._result(entry)
+                self._maybe_validate(entry, res, validate,
+                                     f"query {name!r} lane {i}")
+                results.append(res)
         return results
 
     def adopt(self, name: str, vstate, stats=None, engine: str = "sharded",
@@ -672,28 +724,8 @@ class DiffusionSession:
 
     def _run_spmd(self, program: VertexProgram, backend: str = "xla",
                   sweep: str = "pull"):
-        self._compact_for(program)
-        S = self.n_cells
-        if len(jax.devices()) < S:
-            raise RuntimeError(
-                f"engine='spmd' needs >= {S} devices (one per compute "
-                f"cell); this process has {len(jax.devices())}. Set "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count={S} "
-                f"before importing jax, or use engine='sharded'.")
-        # the per-device fn traces prog.init *inside* shard_map, so the
-        # cache key needs the init identity on top of the program's
-        # (init-excluding) structural equality — see VertexProgram.__eq__
-        fkey = (program, _fn_key(program.init), S, backend, sweep)
-        if fkey not in self._spmd_fns:
-            from ..launch.mesh import make_mesh
-
-            mesh = make_mesh((S,), ("cells",))
-            self._spmd_fns[fkey] = make_spmd_diffuse(
-                mesh, program, self.sg, axis_name="cells",
-                max_local_iters=self.max_local_iters,
-                max_rounds=self.max_rounds, backend=backend, sweep=sweep)
-        return self._spmd_fns[fkey](
-            _sg_as_dict(self.sg, with_push=sweep != "pull"))
+        return self._prepare_diffusion(program, "spmd", backend, None,
+                                       sweep)()
 
     def _result(self, entry: _Entry) -> Result:
         values = self.to_global(entry.vstate[entry.value_key])
@@ -808,11 +840,13 @@ class DiffusionSession:
             seq = None
             if journal and self._journal is not None:
                 # snapshot the op lists BEFORE apply (apply clears them)
-                rec = OpRecord.from_batch(self._pending)
-                seq = self._journal.append(rec)
+                with trace.stage(trace.COMMIT_JOURNAL):
+                    rec = OpRecord.from_batch(self._pending)
+                    seq = self._journal.append(rec)
                 chaos.point("commit.journal-appended")
             try:
-                self.part.sg, applied = self._pending.apply(self.part.sg)
+                with trace.stage(trace.COMMIT_APPLY):
+                    self.part.sg, applied = self._pending.apply(self.part.sg)
             except Exception:
                 # the store rejected the batch — un-journal it (ChaosKill
                 # is a BaseException and deliberately escapes this)
@@ -838,22 +872,34 @@ class DiffusionSession:
 
     def _repair_entry(self, entry: _Entry, applied: AppliedUpdates,
                       mli: int):
-        sg = self.sg
+        """Repair one cached fixed point; the host stage
+        ``repro.commit.repair.<strategy>``."""
         if entry.spec.run_fn is not None:
+            strategy = "recount"
+        else:
+            strategy = entry.spec.repair
+            if not applied.has_deletes and entry.spec.monotone:
+                strategy = "frontier"
+            elif strategy == "parents" and "parent" not in entry.vstate:
+                strategy = "restart"
+        with trace.stage(f"{trace.COMMIT_REPAIR}.{strategy}"):
+            return strategy, self._repair(entry, applied, mli, strategy)
+
+    def _repair(self, entry: _Entry, applied: AppliedUpdates, mli: int,
+                strategy: str):
+        """-> the repaired entry's stats."""
+        sg = self.sg
+        if strategy == "recount":
             # custom queries (triangles): restart-style recount against
             # the committed graph — cached and repaired like any program
             res = entry.spec.run_fn(self, engine=entry.engine,
                                     **entry.kwargs)
             entry.raw, entry.stats = res, res.stats
-            return ("recount", res.stats)
-        strategy = entry.spec.repair
-        if not applied.has_deletes and entry.spec.monotone:
-            strategy = "frontier"
-        elif strategy == "parents" and "parent" not in entry.vstate:
-            strategy = "restart"
+            return res.stats
 
         if strategy == "restart":
-            self._compact_for(entry.prog)
+            with trace.stage(trace.COMMIT_COMPACT):
+                self._compact_for(entry.prog)
             sg = self.sg            # _compact_for may have persisted
             if entry.engine == "spmd":
                 vstate, stats = self._run_spmd(entry.prog, entry.backend,
@@ -866,7 +912,7 @@ class DiffusionSession:
                                         backend=entry.backend,
                                         sweep=entry.sweep or self.sweep)
             entry.vstate, entry.stats = vstate, stats
-            return ("restart", stats)
+            return stats
 
         vstate, active = self._warm_state(entry, applied, strategy)
         # resume under the entry's own delta gate + kernel backend, so the
@@ -881,7 +927,7 @@ class DiffusionSession:
                                      backend=entry.backend,
                                      sweep=entry.sweep or "push")
         entry.vstate, entry.stats = vstate, stats
-        return (strategy, stats)
+        return stats
 
     # -- repair state builders -------------------------------------------
 
@@ -933,7 +979,7 @@ class DiffusionSession:
             dead = set(applied.vertex_deletes)
             for u, v in applied.edge_deletes:
                 sv, lv = self.ns.resolve(v)
-                if int(parent[sv, lv]) == u:
+                if int(trace.to_host(parent[sv, lv], "repair.parents")) == u:
                     roots.append(v)
             if dead:
                 par_np = self.to_global(parent)
@@ -961,10 +1007,12 @@ class DiffusionSession:
             for u, v in applied.edge_deletes:
                 for g in (u, v):
                     s_, l_ = self.ns.resolve(g)
-                    affected.add(int(comp[s_, l_]))
+                    affected.add(int(trace.to_host(comp[s_, l_],
+                                                   "repair.component")))
             for g in applied.vertex_deletes:
                 s_, l_ = self.ns.resolve(g)
-                affected.add(int(comp[s_, l_]))
+                affected.add(int(trace.to_host(comp[s_, l_],
+                                               "repair.component")))
             if affected:
                 init_v, _ = entry.prog.init(logical_view(sg))
                 aff = jnp.isin(comp, jnp.asarray(sorted(affected),
@@ -990,7 +1038,7 @@ class DiffusionSession:
             return
         # explicit d2h transfer: legal under the runtime sanitizer's
         # transfer guard (same idiom as exact_streams_for)
-        if bool(jax.device_get(conv)):
+        if bool(trace.to_host(conv, "budget")):
             return
         msg = (f"{context} exhausted max_rounds={self.max_rounds} before "
                f"quiescence — the fixed point is PARTIAL "

@@ -1,0 +1,300 @@
+#!/usr/bin/env python3
+"""Read the program's spans, scopes and host reads on the query cell's
+graph, on the chip, and print what share of the device time each
+``diffuse.*`` scope takes.
+
+    python3 tools/trace_probe.py [--scale 16] [--seed N] [--keys 3] [--out F]
+
+It builds the session of ``bench/configs/graph500-s16.json`` (the graph
+from its ``graph_seed``, ids relabeled by ``--seed``), warms bfs and sssp
+up, then runs bfs and sssp from ``--keys`` search keys twice: untraced,
+with per-stage host seconds and host reads from a ``jax.monitoring``
+listener, and under the profiler, each query inside a ``bench.query``
+span as the benchmark's loop makes them.
+
+The chip's ``XLA Ops`` events carry no op metadata, so a device op's
+scope comes from the compiled HLO of ``_run_rounds``: each instruction's
+``op_name`` holds the ``jax.named_scope`` path, and its innermost
+``diffuse.*`` name is the op's scope.  The module is compiled afresh with
+the persistent cache off (an executable loaded from the cache prints no
+metadata).  The compiler leaves some fusions without metadata (the
+segment-combine scatters); such a fusion takes the commonest scope of
+the instructions in the computations it calls, nested fusions included.
+Device ops outside a ``_run_rounds`` module (eager init, per-call
+gathers) count as ``unscoped``.
+
+It prints, and writes as JSON to ``--out``: the share of device self
+time per scope inside ``repro.query``; per query the device-idle time
+inside each ``repro.query.*`` stage; relax time per sub-iteration,
+exchange time per round, host reads per query; the top ops as
+``<scope>/<hlo name>``; and the longest idle gaps of the traced window,
+each named by the innermost ``bench.*`` or ``repro.*`` span it overlaps.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import glob
+import json
+import os
+import re
+import sys
+import tempfile
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+
+from bench.lib import graph as G  # noqa: E402
+from bench.lib import measure as M  # noqa: E402
+from repro.core import trace  # noqa: E402
+from repro.core.diffuse import _run_rounds, initial_state  # noqa: E402
+from repro.core.programs import PROGRAMS  # noqa: E402
+from repro.core.session import DiffusionSession  # noqa: E402
+
+PROGS = ("bfs", "sssp")
+_SCOPE_RE = re.compile("|".join(re.escape(s) for s in trace.SCOPES))
+
+
+def scope_of(op_name):
+    found = _SCOPE_RE.findall(op_name or "")
+    return found[-1] if found else "unscoped"
+
+
+def load_json(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+def build(scale: int, seed: int, n_keys: int):
+    cfg = load_json(os.path.join(ROOT, "bench/configs/graph500-s16.json"))
+    cfg["generator"]["scale"] = scale
+    g0 = G.graph500(cfg["generator"], int(cfg["graph_seed"]))
+    g, lab = G.relabel_within_cells(g0, int(cfg["relabel_blocks"]), seed)
+    keys0, warm = G.search_keys(g0, int(cfg["search_keys"]),
+                                int(cfg["graph_seed"]))
+    keys = [int(lab[k]) for k in keys0[:2 * n_keys]]
+    sess = DiffusionSession.from_edges(g.src, g.dst, g.n, g.w,
+                                       engine="sharded", edge_slack=0.0)
+    lone = np.flatnonzero(np.bincount(g.src, minlength=g.n) == 0)
+    root = int(lone[0]) if lone.size else int(lab[warm])
+    for p in PROGS:
+        sess.query(p, source=root)
+    return sess, keys
+
+
+def run_queries(sess, plan, events, annotate: bool):
+    rows = []
+    for p, k in plan:
+        events.clear()
+        t0 = time.perf_counter()
+        if annotate:
+            with jax.profiler.TraceAnnotation("bench.query"):
+                r = sess.query(p, source=k, refresh=True)
+        else:
+            r = sess.query(p, source=k, refresh=True)
+        rows.append({"prog": p, "wall": time.perf_counter() - t0,
+                     "stages": [e for e in events if e[0] == "dur"],
+                     "reads": sum(e[0] == "read" for e in events),
+                     "rounds": int(r.stats.rounds),
+                     "local_iters": int(r.stats.local_iters)})
+    return rows
+
+
+def hlo_scopes(sess, prog_name: str, key: int) -> dict:
+    """HLO instruction name -> scope of ``_run_rounds`` for one program."""
+    prog = PROGRAMS[prog_name].factory(source=key)
+    v0, a0 = initial_state(sess.sg, prog)
+    txt = _run_rounds.lower(sess.sg, prog, v0, a0, sess.max_local_iters,
+                            sess.max_rounds, None, sess.backend,
+                            sess.sweep).compile().as_text()
+    comp_of, op_of, calls = {}, {}, {}
+    comp_ops = collections.defaultdict(list)
+    cur = None
+    for line in txt.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = head.group(1)
+            continue
+        m = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        if not m:
+            continue
+        name = m.group(1)
+        on = re.search(r'op_name="([^"]*)"', line)
+        op_of[name] = on.group(1) if on else None
+        comp_of[name] = cur
+        c = re.search(r"calls=%([\w.\-]+)", line)
+        if c:
+            calls[name] = c.group(1)
+        comp_ops[cur].append(op_of[name])
+    comp_calls = collections.defaultdict(list)
+    for name, c in calls.items():
+        comp_calls[comp_of[name]].append(c)
+
+    def comp_scopes(c, seen):
+        if c in seen:
+            return collections.Counter()
+        seen.add(c)
+        cnt = collections.Counter(scope_of(x) for x in comp_ops[c] if x)
+        for sub in comp_calls[c]:
+            cnt.update(comp_scopes(sub, seen))
+        return cnt
+
+    smap = {}
+    for name, on in op_of.items():
+        s = scope_of(on)
+        if s == "unscoped" and name in calls:
+            cnt = comp_scopes(calls[name], set())
+            cnt.pop("unscoped", None)
+            if cnt:
+                s = cnt.most_common(1)[0][0]
+        smap[name] = s
+    return smap
+
+
+def read_events(log_dir: str):
+    """(device ops, _run_rounds module events, host spans) of the trace."""
+    path = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    ops, mods, spans = [], [], []
+    for plane in data.planes:
+        if plane.name.startswith("/device:"):
+            for line in plane.lines:
+                ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                      for e in line.events]
+                if line.name == "XLA Ops":
+                    ops += ev
+                elif line.name == "XLA Modules":
+                    mods += [m for m in ev
+                             if m[0].startswith("jit__run_rounds")]
+        elif plane.name.startswith("/host:"):
+            spans += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                      for line in plane.lines for e in line.events
+                      if e.name.startswith(("bench.", "repro."))]
+    spans.sort(key=lambda s: s[1])
+    return ops, mods, spans
+
+
+def reduce_trace(ops, mods, spans, plan, hlo_maps):
+    queries = [s for s in spans if s[0] == trace.QUERY]
+    scope_ns, by_op, rows = (collections.Counter(), collections.Counter(),
+                             [])
+    for (_, q0, q1), (p, _) in zip(queries, plan):
+        inside = [e for e in ops if e[1] >= q0 and e[2] <= q1]
+        rr = [m for m in mods if m[1] >= q0 and m[2] <= q1]
+        in_rr = [e for e in inside
+                 if any(m[1] <= e[1] and e[2] <= m[2] for m in rr)]
+        rest = [e for e in inside if e not in in_rr]
+        q_scope = collections.Counter()
+        for name, t in M.self_times(in_rr):
+            sn = M.short_name(name)
+            s = hlo_maps[p].get(sn, "unscoped")
+            q_scope[s] += t
+            by_op[f"{s}/{sn}"] += t
+        for name, t in M.self_times(rest):
+            q_scope["unscoped"] += t
+            by_op[f"unscoped/{M.short_name(name)}"] += t
+        merged = M.union([(a, b) for _, a, b in inside], q0, q1)
+        busy = M.covered(merged, q0, q1)
+        idle = collections.Counter()
+        for sn, s0, s1 in spans:
+            if sn.startswith(trace.QUERY + ".") and s0 >= q0 and s1 <= q1:
+                idle[sn] += (s1 - s0) - M.covered(merged, s0, s1)
+        rows.append({"prog": p, "span_ms": (q1 - q0) * 1e-6,
+                     "busy_ms": busy * 1e-6,
+                     "idle_ms": (q1 - q0 - busy) * 1e-6,
+                     "idle_ms_by_stage": {k: v * 1e-6
+                                          for k, v in idle.items()},
+                     "self_ms_by_scope": {k: v * 1e-6
+                                          for k, v in q_scope.items()}})
+        scope_ns.update(q_scope)
+    return scope_ns, by_op, rows
+
+
+def innermost_gaps(ops, spans, top: int = 10):
+    win = [s for s in spans if s[0] == "bench.window"][-1]
+    merged = M.union([(a, b) for _, a, b in ops], win[1], win[2])
+    edges = [win[1]] + [x for ab in merged for x in ab] + [win[2]]
+    gaps = sorted([(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a],
+                  key=lambda x: x[0] - x[1])[:top]
+    named = []
+    for a, b in gaps:
+        best = None
+        for n, s0, s1 in spans:
+            if n == "bench.window" or min(b, s1) <= max(a, s0):
+                continue
+            if best is None or (s1 - s0) < (best[2] - best[1]):
+                best = (n, s0, s1)
+        named.append([best[0] if best else "host", (b - a) * 1e-9])
+    return named
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--scale", type=int, default=16)
+    ap.add_argument("--seed", type=int, default=2147483999)
+    ap.add_argument("--keys", type=int, default=3)
+    ap.add_argument("--out", default="artifacts/trace_probe.json")
+    args = ap.parse_args()
+
+    sess, keys = build(args.scale, args.seed, args.keys)
+    events = []
+    jax.monitoring.register_scalar_listener(
+        lambda ev, v, **kw: events.append(("read", kw.get("site"), v))
+        if ev == trace.HOST_READ_BYTES else None)
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda ev, d, **_: events.append(("dur", ev[len(trace.EVENT_PREFIX):],
+                                          d))
+        if ev.startswith(trace.EVENT_PREFIX + trace.QUERY) else None)
+
+    plan_untraced = [(p, k) for k in keys[args.keys:] for p in PROGS]
+    plan = [(p, k) for k in keys[:args.keys] for p in PROGS]
+    out = {"seed": args.seed, "keys": keys,
+           "untraced": run_queries(sess, plan_untraced, events, False)}
+
+    log_dir = tempfile.mkdtemp()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    with jax.profiler.TraceAnnotation("bench.window"):
+        out["traced"] = run_queries(sess, plan, events, True)
+    jax.profiler.stop_trace()
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    hlo_maps = {p: hlo_scopes(sess, p, keys[0]) for p in PROGS}
+    ops, mods, spans = read_events(log_dir)
+    if not ops:
+        raise SystemExit("the trace holds no device ops")
+    scope_ns, by_op, rows = reduce_trace(ops, mods, spans, plan, hlo_maps)
+
+    traced = out["traced"]
+    total = sum(scope_ns.values())
+    out.update({
+        "scope_share_pct": {k: 100 * v / total for k, v in scope_ns.items()},
+        "sweep_ms": scope_ns[trace.SCOPE_RELAX] * 1e-6
+        / sum(q["local_iters"] for q in traced),
+        "exchange_ms_per_round": scope_ns[trace.SCOPE_EXCHANGE] * 1e-6
+        / sum(q["rounds"] for q in traced),
+        "query_host_idle_ms": sum(q["idle_ms"] for q in rows) / len(rows),
+        "host_reads_per_query": sum(q["reads"] for q in traced)
+        / len(traced),
+        "top_ops": [[k, v * 1e-9] for k, v in by_op.most_common(20)],
+        "idle_gaps": innermost_gaps(ops, spans),
+        "per_query": rows,
+    })
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(out, f, indent=1, default=str)
+    print(json.dumps({k: v for k, v in out.items()
+                      if k not in ("per_query", "untraced", "traced")},
+                     default=str))
+    for q in rows:
+        print(json.dumps(q))
+
+
+if __name__ == "__main__":
+    main()
