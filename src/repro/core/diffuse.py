@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .graph import DEFAULT_EDGE_BLOCK, ShardedGraph
+from .msg import run_ends
 from .partition import Partitioned
 from .programs import VertexProgram
 from .relax import (
@@ -199,7 +200,9 @@ def _sg_as_dict(sg: ShardedGraph, with_push: bool = False):
     """ShardedGraph -> the engine-facing array dict: the per-cell vertex
     block (``node_ok``/``gid``/``out_degree``) plus the destination-sorted
     pull streams the relax backends consume (``csr_key`` live-masked,
-    ``csr_skey`` structural — see DESIGN.md §2.9) — and, when
+    ``csr_skey`` structural — see DESIGN.md §2.9 — and ``csr_run_end``,
+    the sorted region's run layout, :func:`~.msg.run_ends`: computed here
+    once per dispatch, not in every sweep) — and, when
     ``with_push`` (any sweep that can compact), the source-sorted push
     streams too (built on demand for graphs with invalidated views).
     The unsorted edge arrays always stay out, and the push streams stay
@@ -214,6 +217,10 @@ def _sg_as_dict(sg: ShardedGraph, with_push: bool = False):
         "out_degree": sg.out_degree,
     }
     d.update(sg.csr_view())
+    es = d["csr_skey"].shape[-1] - sg.delta_width
+    n_keys = sg.n_shards * sg.n_per_shard
+    d["csr_run_end"] = jax.vmap(lambda k: run_ends(k, n_keys))(
+        d["csr_skey"][:, :es])
     if with_push:
         d.update(sg.push_view())
     if sg.replica_members is not None:

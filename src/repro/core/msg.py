@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "block_any",
+    "run_ends",
     "segment_combine",
     "identity_for",
     "segment_softmax",
@@ -46,6 +47,19 @@ def block_any(mask: jnp.ndarray, block: int) -> jnp.ndarray:
     win = (1,) * (mask.ndim - 1) + (block,)
     return jax.lax.reduce_window(mask, False, jax.lax.bitwise_or, win, win,
                                  "VALID")
+
+
+def run_ends(skey: jnp.ndarray, n_keys: int) -> jnp.ndarray:
+    """The run layout of a key-sorted stream ``skey`` [E] (``-1`` padding
+    trailing): [n_keys] int32, the position of the last element of key
+    k's run, or ``-1`` where k has no element.  Depends only on the
+    stream's structure, so a sweep over an unchanged stream computes it
+    once and reads each key's run total with one gather."""
+    key2 = jnp.where(skey < 0, n_keys, skey).astype(jnp.int32)
+    ks = jnp.arange(n_keys, dtype=jnp.int32)
+    last = jnp.searchsorted(key2, ks, side="right").astype(jnp.int32) - 1
+    ok = (last >= 0) & (key2[jnp.clip(last, 0)] == ks)
+    return jnp.where(ok, last, -1)
 
 
 def segment_combine(

@@ -6,9 +6,10 @@ the same bulk-asynchronous while-loop structure; what differs per backend is
 only how one cell turns its vertex block + edge stream into the combined
 per-destination message table:
 
-* ``"xla"``     — segment ops over the sorted stream (flat for the
-  order-free min/max monoids, segmented scan for sum); the default and
-  the CPU/GPU production path.
+* ``"xla"``     — the segmented scan over the destination-sorted stream
+  for every program, read out with one gather per destination at its
+  run end (no scatter over the sorted region); the default and the
+  production path.
 * ``"pallas"``  — the fused ``kernels/edge_relax`` block kernel: emit +
   dense-rank in-block combine per 128-edge block (interpret mode
   off-TPU, so CI exercises the same code path).  Min/max programs only
@@ -127,7 +128,8 @@ def make_relax(prog, n_shards: int, n_per_shard: int, block_e: int,
     """Build the per-cell relaxation step for ``prog`` on ``backend``.
 
     The returned function maps one cell's (vstate [Np] pytree, senders
-    [Np] bool, sg_s dict with the ``csr_*``/``push_*`` sorted streams,
+    [Np] bool, sg_s dict with the ``csr_*``/``push_*`` sorted streams and
+    optionally the ``csr_run_end`` run layout,
     and — for push/auto sweeps — the scalar ``bucket`` chosen by
     :func:`select_bucket`) to
 
@@ -198,7 +200,7 @@ def make_relax(prog, n_shards: int, n_per_shard: int, block_e: int,
             sg_s["csr_dst_gid"],
             n_keys=n_keys, block_e=block_e, backend=backend,
             interpret=interpret, skey=sg_s.get("csr_skey"),
-            delta_e=delta_e,
+            delta_e=delta_e, run_end=sg_s.get("csr_run_end"),
         )
 
     if sweep == "pull":
@@ -215,7 +217,7 @@ def make_relax(prog, n_shards: int, n_per_shard: int, block_e: int,
             prog, vstate, senders, sg_s["gid"], sg_push, sg_s["csr_key"],
             n_keys=n_keys, block_e=block_e, cap=cap, backend=backend,
             interpret=interpret, skey=sg_s.get("csr_skey"),
-            delta_e=delta_e,
+            delta_e=delta_e, run_end=sg_s.get("csr_run_end"),
         )
 
     def relax(vstate, senders, sg_s, bucket=None):

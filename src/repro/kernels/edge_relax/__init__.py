@@ -16,9 +16,11 @@ level, for the engine's hot loop (one relaxation sweep of one cell):
   kernel, which only emits and combines;
 * the **edge stream is the moving operand** — it arrives in the graph's
   destination-sorted blocked-CSR layout (``ShardedGraph.with_csr``), so
-  each block's messages form contiguous per-destination runs and the
-  in-block combine is a dense-rank one-hot reduction (shared with
-  ``segment_reduce``; MXU-shaped for the sum monoid);
+  each destination's messages form one contiguous run: the XLA path
+  combines them with a segmented scan read out at each run's end (no
+  scatter), the Pallas block kernel with a dense-rank one-hot reduction
+  per block (shared with ``segment_reduce``; MXU-shaped for the sum
+  monoid);
 * the result is the cell's **operon traffic**: a combined per-destination
   message table over the flat ``(dst_shard, dst_local)`` key space — row
   *self* is the local inbox, the other rows are the coalesced cross-cell

@@ -12,15 +12,16 @@ message table over the flat key space ``dst_shard * Np + dst_local``:
 Lane-stacked inputs (``senders`` [L, Np] — multi-query lanes) broadcast
 the sweep over lanes and return [L, n_keys] tables.
 
-Dispatch: sum programs and all laned runs take the segmented-scan path
+Dispatch: on ``xla`` every dense sweep takes the segmented-scan path
 (``ref.stream_scan`` — fixed tree order, lane- and block-independent, so
-lanes are bitwise-equal to solo queries); single-query min/max keeps the
-flat segment path on ``xla`` and the fused blocked kernel on ``pallas``
-(order-free monoids agree across all paths).  Phase 2 — the run-end
-gather (scan) / cross-block scatter (blocked) — is XLA code shared
-verbatim by both backends, so the two are bitwise-identical — asserted
-program-by-program in tests/test_session and per-lane in
-tests/test_lanes.
+lanes are bitwise-equal to solo queries; over the destination-sorted
+stream it needs no scatter, and each destination's table entry is one
+gather at its run end).  On ``pallas`` sum programs take the scan kernel
+and min/max the fused blocked kernel (order-free monoids agree across
+all paths).  Phase 2 — the run-end gather (scan) / cross-block scatter
+(blocked) — is XLA code shared verbatim by both backends, so the two are
+bitwise-identical — asserted program-by-program in tests/test_session
+and per-lane in tests/test_lanes.
 """
 
 from __future__ import annotations
@@ -28,14 +29,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ...core.msg import identity_for
+from ...core.msg import identity_for, run_ends
 from ...core.relax import RELAX_BACKENDS
 from .kernel import edge_relax_blocks, edge_relax_push_blocks, edge_relax_scan
 from .ref import (
     compact_push_blocks,
     delta_tables,
     edge_messages,
-    edge_relax_flat,
     edge_relax_push_flat,
     edge_relax_push_stream,
     edge_relax_stream,
@@ -73,7 +73,7 @@ def _combine_blocks(part, cnt, uniq, pay, n_keys: int, combine: str,
 def edge_relax_push(prog, vstate, senders, gid, sg_push, csr_key,
                     n_keys: int, block_e: int, cap: int,
                     backend: str = "xla", interpret: bool = False,
-                    skey=None, delta_e: int = 0):
+                    skey=None, delta_e: int = 0, run_end=None):
     """Frontier-compacted push sweep of one cell — the sparse twin of
     :func:`edge_relax`, same (table, cnt, pay) contract.
 
@@ -93,8 +93,8 @@ def edge_relax_push(prog, vstate, senders, gid, sg_push, csr_key,
     positions, DESIGN.md §2.9) needs no special push handling on the
     flat/blocked paths — a delta block is active exactly when one of its
     staged edges' sources sends, so compaction covers it like any sorted
-    block; the stream path forwards ``skey``/``delta_e`` so its dense
-    reconstruction scans only the sorted region.
+    block; the stream path forwards ``skey``/``delta_e``/``run_end`` so
+    its dense reconstruction scans only the sorted region.
     """
     if backend not in RELAX_BACKENDS:
         raise ValueError(
@@ -104,7 +104,8 @@ def edge_relax_push(prog, vstate, senders, gid, sg_push, csr_key,
     if prog.combine == "sum" or laned:
         return edge_relax_push_stream(prog, vstate, senders, gid, sg_push,
                                       csr_key, n_keys, block_e, cap,
-                                      skey=skey, delta_e=delta_e)
+                                      skey=skey, delta_e=delta_e,
+                                      run_end=run_end)
     if backend == "xla":
         return edge_relax_push_flat(prog, vstate, senders, gid, sg_push,
                                     n_keys, block_e, cap)
@@ -118,7 +119,8 @@ def edge_relax_push(prog, vstate, senders, gid, sg_push, csr_key,
 
 def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
                n_keys: int, block_e: int, backend: str = "xla",
-               interpret: bool = False, skey=None, delta_e: int = 0):
+               interpret: bool = False, skey=None, delta_e: int = 0,
+               run_end=None):
     """One relaxation sweep of one cell; see module docstring for the
     returned (table, cnt, pay) contract.
 
@@ -130,11 +132,13 @@ def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
     Incremental streams (DESIGN.md §2.9): ``key`` is the live-masked
     destination key (tombstones read ``-1`` and never send) and ``skey``
     the structural sorted key; ``delta_e`` trailing positions are the
-    staged delta segment.  The flat and blocked paths consume tombstones
-    and delta blocks through their ordinary masking/scatter handling; the
-    scan paths scan the sorted region against ``skey`` and fold the
-    (unsorted) delta segment in through the shared
-    :func:`~.ref.delta_tables` scatter."""
+    staged delta segment.  The blocked path consumes tombstones and delta
+    blocks through its ordinary masking/scatter handling; the scan paths
+    scan the sorted region against ``skey`` and fold the (unsorted) delta
+    segment in through the shared :func:`~.ref.delta_tables` scatter.
+    ``run_end`` is the run layout of ``skey``'s sorted region
+    (:func:`~repro.core.msg.run_ends`), computed once per dispatch by the
+    engines; computed per call when omitted."""
     if backend not in RELAX_BACKENDS:
         raise ValueError(
             f"backend must be one of {RELAX_BACKENDS}, got {backend!r}")
@@ -142,24 +146,25 @@ def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
         skey = key
     laned = senders.ndim == 2      # [L, Np] lane-stacked vertex block
 
-    # Sum programs take the segmented-scan path on *both* backends: its
-    # fixed tree order is independent of block boundaries and lane count,
-    # which is what makes a lane's sum bitwise-equal to the same query
-    # run solo (laned min/max take it on xla for speed — order-free
-    # monoids match every other path bitwise anyway).
-    if prog.combine == "sum" or (laned and backend == "xla"):
-        if backend == "xla":
-            return edge_relax_stream(prog, vstate, senders, gid, key, src,
-                                     weight, dst_gid, n_keys, skey=skey,
-                                     delta_e=delta_e)
+    # Every program takes the segmented-scan path on xla, and sum
+    # programs on both backends: its fixed tree order is independent of
+    # block boundaries and lane count, which is what makes a lane's sum
+    # bitwise-equal to the same query run solo (order-free min/max match
+    # every other path bitwise anyway).
+    if backend == "xla":
+        return edge_relax_stream(prog, vstate, senders, gid, key, src,
+                                 weight, dst_gid, n_keys, skey=skey,
+                                 delta_e=delta_e, run_end=run_end)
+    if prog.combine == "sum":
         es = key.shape[-1] - delta_e
+        if run_end is None:
+            run_end = run_ends(skey[:es], n_keys)
         scan1 = lambda vs, sd: edge_relax_scan(
             prog, vs, sd, gid, key[:es], src[:es], weight[:es],
             dst_gid[:es], skey=skey[:es], interpret=interpret)
         scanned = (jax.vmap(scan1)(vstate, senders) if laned
                    else scan1(vstate, senders))
-        out = gather_runs(scanned, skey[:es], n_keys, prog.monoid,
-                          prog.msg_dtype)
+        out = gather_runs(scanned, run_end, prog.monoid, prog.msg_dtype)
         if delta_e:
             # delta tail: shared XLA phase (message bodies + scatter),
             # merged by the monoid — same code the XLA path runs
@@ -178,9 +183,6 @@ def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
                 interpret=interpret, skey=skey, delta_e=delta_e,
             )
         )(vstate, senders)
-    if backend == "xla":
-        return edge_relax_flat(prog, vstate, senders, gid, key, src,
-                               weight, dst_gid, n_keys)
     part, cnt, uniq, pay = edge_relax_blocks(
         prog, vstate, senders, gid, key, src, weight, dst_gid, block_e,
         interpret=interpret)

@@ -9,16 +9,17 @@ Two *single-source-of-truth* bodies keep the backends bitwise-identical:
   fixed tree order depends only on the stream length — never on lane
   count or block boundaries — which is what lets the engine promise that
   a query lane reproduces the same query run solo bit-for-bit, even for
-  the order-sensitive sum monoid.  The canonical sum path and the fast
-  path for all multi-query-lane runs.
+  the order-sensitive sum monoid.  The dense pull path of every program
+  on ``xla``: it reads the sorted region with slices, elementwise ops
+  and one gather per destination at its run end — no scatter.
 * ``block_combine`` — the blocked dense-rank segment combine executed
   verbatim by the blocked Pallas kernel
   (:func:`~.kernel.edge_relax_blocks`).
 
-``edge_relax_flat`` is the fast unblocked path for single-query
-order-free monoids (min/max): plain segment ops over the sorted stream.
-Min/max over a set is association-free, so flat, blocked, and scan all
-agree bitwise by construction.
+``flat_combine`` is the unsorted segment combine (scatters by
+destination) of the frontier-compacted push sweep, whose gathered blocks
+are not destination-sorted.  Min/max over a set is association-free, so
+flat, blocked, and scan all agree bitwise by construction.
 """
 
 from __future__ import annotations
@@ -26,7 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ...core.msg import block_any, identity_for, segment_combine
+from ...core.msg import block_any, identity_for, run_ends, segment_combine
 
 __all__ = [
     "edge_messages",
@@ -34,7 +35,6 @@ __all__ = [
     "emit_messages",
     "block_combine",
     "flat_combine",
-    "edge_relax_flat",
     "stream_scan",
     "gather_runs",
     "delta_tables",
@@ -90,12 +90,12 @@ def edge_messages(prog, vstate, senders, gid, key, src, weight, dst_gid):
 
 
 def flat_combine(cand, send, pay, ids, n_keys: int, combine: str):
-    """Phase 2 of the unsorted segment paths: scatter-combine the
+    """Phase 2 of the unsorted segment path: scatter-combine the
     candidate messages by destination id (``n_keys`` = drop row), count
     senders, and ride the argbest payload with the segment-max-over-
-    winners tie-break.  Shared verbatim by the dense flat path and the
-    compacted push path — the push == pull bitwise contract for payload
-    programs lives here, structurally."""
+    winners tie-break — the same rule as :func:`stream_scan`'s payload
+    fold, so the compacted push sweep stays bitwise-equal to the dense
+    pull sweep for payload programs."""
     table = segment_combine(cand, ids, n_keys + 1, combine,
                             indices_are_sorted=False)
     cnt = segment_combine(send.astype(jnp.int32), ids, n_keys + 1, "sum")
@@ -169,35 +169,45 @@ def _block_scan(comb, elems):
     Each level scans blocks of ``SCAN_BLOCK`` consecutive elements by
     log-step shifts (Hillis-Steele, masked at block starts), scans the
     block totals recursively, and folds each block's carry in from the
-    left.  Everything stays on the flat stream axis: slices, elementwise
-    ops and gathers only.  ``lax.associative_scan``'s odd/even recursion
-    builds one strided shape per level, and the TPU compiler's time on it
-    grows about 4x per doubling of E (104 s at E = 2^20, 409 s at 2^21,
-    v5e target); reshaping a [S, E] stream to [S, E/128, 128] blocks
-    costs about as much again (~100 s at E = 2^24.3 per cell), so the
-    blocks are never materialized.
+    left.  The block totals are read with one gather of E/128 indices;
+    the carries go back out to E positions as a broadcast of the E/128
+    block prefixes over their 128 lanes — no E-long gather, so a level
+    costs elementwise work only.  ``lax.associative_scan``'s odd/even
+    recursion builds one strided shape per level, and the TPU compiler's
+    time on it grows about 4x per doubling of E (104 s at E = 2^20, 409 s
+    at 2^21, v5e target); reshaping a [S, E] stream to [S, E/128, 128]
+    blocks costs about as much again (~100 s at E = 2^24.3 per cell), so
+    the elements are never blocked: only the carries are, in the
+    direction that broadcasts.
     """
     e = elems[0].shape[-1]
     b = SCAN_BLOCK
     lane = jnp.arange(e, dtype=jnp.int32) % b
     d = 1
     while d < min(b, e):
-        right = comb(tuple(a[..., :-d] for a in elems),
-                     tuple(a[..., d:] for a in elems))
-        keep = lane[d:] >= d
-        elems = tuple(
-            jnp.concatenate([a[..., :d], jnp.where(keep, r, a[..., d:])],
-                            axis=-1)
-            for a, r in zip(elems, right))
+        # each element folds in the one d to its left, within its block;
+        # the pad (a shift, not a concatenate of two slices) keeps a level
+        # one fusion per array on the TPU: half the compile time
+        pad = [(0, 0)] * (elems[0].ndim - 1) + [(d, 0)]
+        right = comb(tuple(jnp.pad(a[..., :-d], pad) for a in elems), elems)
+        elems = tuple(jnp.where(lane >= d, r, a)
+                      for a, r in zip(elems, right))
         d *= 2
     if e <= b:
         return elems
     nb = -(-e // b)
     last = jnp.minimum(jnp.arange(nb, dtype=jnp.int32) * b + (b - 1), e - 1)
     tot = _block_scan(comb, tuple(jnp.take(a, last, axis=-1) for a in elems))
-    # the carry into block j >= 1 is the inclusive scan of blocks < j
+
+    def expand(t):
+        # the carry into block j >= 1 is the inclusive scan of blocks < j
+        # (block 0's carry is masked off below)
+        t = jnp.concatenate([t[..., :1], t[..., :-1]], axis=-1)
+        t = jnp.broadcast_to(t[..., None], t.shape + (b,))
+        return t.reshape(t.shape[:-2] + (nb * b,))[..., :e]
+
     blk = jnp.arange(e, dtype=jnp.int32) // b
-    carry = tuple(jnp.take(t, jnp.maximum(blk - 1, 0), axis=-1) for t in tot)
+    carry = tuple(expand(t) for t in tot)
     folded = comb(carry, elems)
     return tuple(jnp.where(blk >= 1, f, a) for f, a in zip(folded, elems))
 
@@ -256,19 +266,17 @@ def stream_scan(monoid, cand, send, key, pay):
     return v, c, p
 
 
-def gather_runs(scanned, key, n_keys: int, monoid, msg_dtype):
+def gather_runs(scanned, run_end, monoid, msg_dtype):
     """Phase 2 of the scan path: read each destination's run total.
 
-    The stream is sorted, so the last element of destination k's run sits
-    at ``searchsorted(key, k, 'right') - 1`` — a pure gather (no scatter),
-    lane-batched for free.  Shared XLA code for both backends.
+    ``run_end`` [n_keys] is the sorted region's run layout
+    (:func:`~repro.core.msg.run_ends`: destination k's last position, or
+    ``-1``), so the read is one pure gather per destination (no
+    scatter), lane-batched for free.  Shared XLA code for both backends.
     """
     v, c, p = scanned
-    key2 = jnp.where(key < 0, n_keys, key).astype(jnp.int32)
-    ks = jnp.arange(n_keys, dtype=jnp.int32)
-    last = jnp.searchsorted(key2, ks, side="right").astype(jnp.int32) - 1
-    li = jnp.clip(last, 0)
-    ok = (last >= 0) & (key2[li] == ks)
+    ok = run_end >= 0
+    li = jnp.maximum(run_end, 0)
     ident = monoid.identity(msg_dtype)
     table = jnp.where(ok, jnp.take(v, li, axis=-1), ident)
     cnt = jnp.where(ok, jnp.take(c, li, axis=-1), 0)
@@ -333,7 +341,7 @@ def merge_tables(prog, a, b):
 
 
 def stream_combine(prog, cand, send, pay, key, skey, n_keys: int,
-                   delta_e: int):
+                   delta_e: int, run_end=None):
     """The one home of the sorted-region/delta-segment split: segmented
     scan + run-end gather over ``[..., :es]`` against the structural
     ``skey``, with the staged delta segment (``delta_e`` trailing
@@ -342,13 +350,18 @@ def stream_combine(prog, cand, send, pay, key, skey, n_keys: int,
     (dense scan path, push-sweep reconstruction) calls this, so the
     'incremental == rebuild bitwise' contract cannot drift between
     backends or sweeps.
+
+    ``run_end`` is ``run_ends(skey[:es], n_keys)``; the engines compute
+    it once per dispatch (``csr_run_end``), and it is computed here when
+    not given.
     """
     es = key.shape[-1] - delta_e
+    if run_end is None:
+        run_end = run_ends(skey[:es], n_keys)
     sl = lambda a: None if a is None else a[..., :es]
     scanned = stream_scan(prog.monoid, cand[..., :es], send[..., :es],
                           skey[:es], sl(pay))
-    out = gather_runs(scanned, skey[:es], n_keys, prog.monoid,
-                      prog.msg_dtype)
+    out = gather_runs(scanned, run_end, prog.monoid, prog.msg_dtype)
     if delta_e:
         dl = lambda a: None if a is None else a[..., es:]
         out = merge_tables(prog, out, delta_tables(
@@ -358,18 +371,19 @@ def stream_combine(prog, cand, send, pay, key, skey, n_keys: int,
 
 
 def edge_relax_stream(prog, vstate, senders, gid, key, src, weight, dst_gid,
-                      n_keys: int, skey=None, delta_e: int = 0):
+                      n_keys: int, skey=None, delta_e: int = 0,
+                      run_end=None):
     """Scan-based relaxation sweep (XLA): gather → emit → segmented scan
     over the sorted region → run-end gather, plus the shared-index
     scatter over the staged delta segment (``delta_e`` trailing
     positions) merged in by the monoid (:func:`stream_combine`).
     Handles single ([Np] leaves) and laned ([L, Np]) vertex blocks
-    uniformly; the canonical sum path and the fast path for every laned
-    program.
+    uniformly; the dense pull path of every program on ``xla``.
 
     ``key`` is the live-masked destination key (tombstones ``-1``) used
     for send masking; ``skey`` the structural sorted key driving the
-    run layout (defaults to ``key`` — identical on delta-free graphs).
+    run layout (defaults to ``key`` — identical on delta-free graphs);
+    ``run_end`` its precomputed run ends (:func:`stream_combine`).
 
     Returns (table [..., n_keys], cnt, pay | None).
     """
@@ -378,7 +392,7 @@ def edge_relax_stream(prog, vstate, senders, gid, key, src, weight, dst_gid,
     cand, send, pay = edge_messages(prog, vstate, senders, gid, key, src,
                                       weight, dst_gid)
     return stream_combine(prog, cand, send, pay, key, skey, n_keys,
-                          delta_e)
+                          delta_e, run_end)
 
 
 # --------------------------------------------------------------------------
@@ -445,11 +459,11 @@ def edge_relax_push_flat(prog, vstate, senders, gid, sg_push, n_keys: int,
     compact -> gather -> emit -> unsorted segment-combine by destination.
 
     The sending-edge multiset is exactly the dense sweep's (inactive
-    blocks hold no senders by construction) and min/max segment scatters
-    are association-free, so the table/cnt/payload triple is bitwise-equal
-    to :func:`edge_relax_flat` — structurally, via the shared
-    :func:`edge_messages` / :func:`flat_combine` bodies — while touching
-    O(cap * block_e) edges.
+    blocks hold no senders by construction) and min/max are
+    association-free, so the table/cnt/payload triple is bitwise-equal
+    to the dense :func:`edge_relax_stream` (the same :func:`edge_messages`
+    body, and :func:`flat_combine`'s tie-break is the scan's) while
+    touching O(cap * block_e) edges.
     """
     idx, _ = compact_push_blocks(senders, sg_push["push_src"], block_e, cap)
     g, _ = push_gather(sg_push, idx, block_e)
@@ -461,7 +475,7 @@ def edge_relax_push_flat(prog, vstate, senders, gid, sg_push, n_keys: int,
 
 def edge_relax_push_stream(prog, vstate, senders, gid, sg_push, csr_key,
                            n_keys: int, block_e: int, cap: int, skey=None,
-                           delta_e: int = 0):
+                           delta_e: int = 0, run_end=None):
     """Frontier-compacted push sweep for sum programs and all laned runs:
     compact -> gather -> emit -> scatter the messages back into the dense
     destination-sorted stream layout (via ``push_pos``) -> the shared
@@ -498,19 +512,5 @@ def edge_relax_push_stream(prog, vstate, senders, gid, sg_push, csr_key,
         pay_full = scat(jnp.full(lane + (e,), -1, jnp.int32),
                         jnp.broadcast_to(pay, cand.shape))
     return stream_combine(prog, cand_full, send_full, pay_full, csr_key,
-                          skey, n_keys, delta_e)
+                          skey, n_keys, delta_e, run_end)
 
-
-def edge_relax_flat(prog, vstate, senders, gid, key, src, weight, dst_gid,
-                    n_keys: int):
-    """Unblocked segment-combine over the sorted stream (min/max only).
-
-    Order-free monoids make this bitwise-equal to the blocked paths while
-    doing O(E) scatter work — the engine's ``backend="xla"`` fast path.
-
-    Returns (table [n_keys], cnt [n_keys] int32, pay [n_keys] | None).
-    """
-    cand, send, pay = edge_messages(prog, vstate, senders, gid, key, src,
-                                    weight, dst_gid)
-    ids = jnp.where(send, key, n_keys)       # non-senders dropped off-range
-    return flat_combine(cand, send, pay, ids, n_keys, prog.combine)

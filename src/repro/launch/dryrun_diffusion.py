@@ -40,7 +40,8 @@ def build_specs(scale: int, n_cells: int, edge_factor: int = 16,
     S = n_cells
     i32 = jnp.int32
     # the engine-facing view (diffuse._sg_as_dict): vertex block + the
-    # destination-sorted pull streams (ShardedGraph.csr_view) and — for
+    # destination-sorted pull streams (ShardedGraph.csr_view) with their
+    # run layout over the n flat destination keys, and — for
     # push/auto sweeps only, mirroring _sg_as_dict — the source-sorted
     # push streams (ShardedGraph.push_view)
     specs = {
@@ -52,6 +53,7 @@ def build_specs(scale: int, n_cells: int, edge_factor: int = 16,
         "csr_src": jax.ShapeDtypeStruct((S, eb), i32),
         "csr_weight": jax.ShapeDtypeStruct((S, eb), jnp.float32),
         "csr_dst_gid": jax.ShapeDtypeStruct((S, eb), i32),
+        "csr_run_end": jax.ShapeDtypeStruct((S, n), i32),
     }
     if with_push:
         specs.update({

@@ -195,3 +195,210 @@ def test_relax_sweep(np_, e):
     both_inf = np.isinf(np.asarray(out)) & np.isinf(np.asarray(ref))
     diff = np.where(both_inf, 0, np.asarray(out) - np.asarray(ref))
     assert np.max(np.abs(diff)) < 1e-5
+
+
+# --------------------------------------------------------------------------
+# dense pull sweep on xla: segmented scan + run-end gathers, no scatters
+# --------------------------------------------------------------------------
+
+def _live_eqns(jaxpr, used=None):
+    """The equations of ``jaxpr`` (and of the jaxprs nested in them) that
+    feed its used outputs — dead source reads left out."""
+    used = [True] * len(jaxpr.outvars) if used is None else used
+    live = {id(v) for v, u in zip(jaxpr.outvars, used) if u}
+    found = []
+    for eqn in reversed(jaxpr.eqns):
+        outs = [id(v) in live for v in eqn.outvars]
+        if not any(outs):
+            continue
+        live.update(id(v) for v in eqn.invars)
+        found.append(eqn)
+        for p in eqn.params.values():
+            if hasattr(p, "eqns"):
+                inner = getattr(p, "jaxpr", p)
+                found += _live_eqns(
+                    inner, outs if len(inner.outvars) == len(outs) else None)
+    return found
+
+
+@pytest.mark.parametrize("delta_e", [0, 256])
+@pytest.mark.parametrize("name,n_reads", [("sssp", 3), ("bfs", 2)])
+def test_pull_sweep_has_no_stream_scatters(name, n_reads, delta_e):
+    """The dense single-query pull sweep on xla reads the sorted region
+    with elementwise scan levels and one gather per destination: its only
+    E-long gathers are the source reads, and only the delta segment takes
+    scatters."""
+    import types
+
+    from repro.core.programs import PROGRAMS
+    from repro.kernels.edge_relax import edge_relax
+
+    prog = PROGRAMS[name].factory(source=0)
+    np_, n_keys, es = 96, 2 * 96, 128 * 24
+    e = es + delta_e
+    sds = jax.ShapeDtypeStruct
+    vstate = jax.eval_shape(
+        lambda g: prog.init(types.SimpleNamespace(
+            gid=g, node_ok=g >= 0, out_degree=g))[0],
+        sds((1, np_), jnp.int32))
+    vstate = jax.tree_util.tree_map(lambda a: sds((np_,), a.dtype), vstate)
+
+    def sweep(vs, senders, gid, key, src, w, dg, run_end):
+        return edge_relax(prog, vs, senders, gid, key, src, w, dg,
+                          n_keys=n_keys, block_e=128, backend="xla",
+                          delta_e=delta_e, run_end=run_end)
+
+    closed = jax.make_jaxpr(sweep)(
+        vstate, sds((np_,), bool), sds((np_,), jnp.int32),
+        sds((e,), jnp.int32), sds((e,), jnp.int32), sds((e,), jnp.float32),
+        sds((e,), jnp.int32), sds((n_keys,), jnp.int32))
+    eqns = _live_eqns(closed.jaxpr)
+    n_idx = lambda eqn: int(np.prod(eqn.invars[1].aval.shape[:-1]))
+    long_gathers = [q for q in eqns if q.primitive.name == "gather"
+                    and n_idx(q) >= es]
+    assert len(long_gathers) == n_reads
+    scatters = [q for q in eqns if q.primitive.name.startswith("scatter")]
+    if not delta_e:
+        assert scatters == []
+    else:
+        assert scatters
+        assert all(q.invars[2].aval.size == delta_e for q in scatters)
+
+
+def _pull_cell(rng, np_, s_, es, delta_e, hub_run):
+    """One cell's streams: a destination-sorted region of width ``es``
+    holding a hub run of ``hub_run`` edges, trailing padding, tombstones,
+    and an unsorted delta segment of ``delta_e`` positions."""
+    n_keys = s_ * np_
+    n_live = es - 100
+    keys = np.sort(np.concatenate([
+        np.full(hub_run, 37), rng.integers(0, n_keys, n_live - hub_run)]))
+    skey = np.concatenate([keys, np.full(es - n_live, -1)])
+    dkey = rng.integers(-1, n_keys, delta_e)
+    skey = np.concatenate([skey, dkey]).astype(np.int32)
+    key = np.where(rng.random(es + delta_e) < 0.1, -1, skey)   # tombstones
+    key[es:] = dkey
+    src = rng.integers(0, np_, es + delta_e).astype(np.int32)
+    # small whole numbers: many exact ties in the combined value
+    w = rng.integers(1, 4, es + delta_e).astype(np.float32)
+    return (jnp.asarray(key), jnp.asarray(skey), jnp.asarray(src),
+            jnp.asarray(w))
+
+
+@pytest.mark.parametrize("delta_e", [0, 384])
+@pytest.mark.parametrize("name", ["sssp", "widest"])
+def test_pull_scan_matches_flat_combine(name, delta_e):
+    """The xla pull sweep (segmented scan + precomputed run ends) returns
+    bitwise the tables of the unsorted segment combine over the same
+    messages: values, sending counts, and the payload tie-break (max
+    payload among the winners)."""
+    from repro.core.msg import run_ends
+    from repro.core.programs import PROGRAMS
+    from repro.kernels.edge_relax import edge_relax
+    from repro.kernels.edge_relax.ref import edge_messages, flat_combine
+
+    rng = np.random.default_rng(15)
+    np_, s_, es = 200, 2, 128 * 14
+    n_keys = s_ * np_
+    prog = PROGRAMS[name].factory(source=0)
+    key, skey, src, w = _pull_cell(rng, np_, s_, es, delta_e, hub_run=1200)
+    dst_gid = skey                     # read by neither program's emit
+    assert int((np.asarray(skey[:es]) == 37).sum()) > 8 * 128
+    gid = jnp.arange(np_, dtype=jnp.int32) + 1000
+    field = "dist" if name == "sssp" else "width"
+    vals = rng.integers(0, 5, np_).astype(np.float32)
+    vals[rng.random(np_) < 0.2] = np.inf if name == "sssp" else -np.inf
+    vstate = {field: jnp.asarray(vals),
+              "parent": jnp.full((np_,), -1, jnp.int32)}
+    senders = jnp.asarray(rng.random(np_) < 0.7)
+    run_end = run_ends(skey[:es], n_keys)
+
+    out = edge_relax(prog, vstate, senders, gid, key, src, w, dst_gid,
+                     n_keys=n_keys, block_e=128, backend="xla", skey=skey,
+                     delta_e=delta_e, run_end=run_end)
+    cand, send, pay = edge_messages(prog, vstate, senders, gid, key, src,
+                                    w, dst_gid)
+    ref = flat_combine(cand, send, pay,
+                       jnp.where(send, key, n_keys), n_keys, prog.combine)
+    for a, b in zip(out, ref):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    cnt = np.asarray(ref[1])
+    assert cnt[37] > 8 * 128 * 0.5                   # the hub's run sends
+    # ties: some destination has several winning senders
+    table = np.asarray(ref[0])
+    win = np.asarray(send) & (np.asarray(cand) == table[
+        np.clip(np.asarray(key), 0, n_keys - 1)]) & (np.asarray(key) >= 0)
+    assert np.bincount(np.asarray(key)[win], minlength=n_keys).max() > 1
+    # the run layout computed in the sweep when not given
+    again = edge_relax(prog, vstate, senders, gid, key, src, w, dst_gid,
+                       n_keys=n_keys, block_e=128, backend="xla", skey=skey,
+                       delta_e=delta_e)
+    for a, b in zip(out, again):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def _gather_carry_scan(comb, elems):
+    """``_block_scan`` with each block's carry read by an E-long gather
+    (its earlier form): the reference for the carry broadcast."""
+    from repro.kernels.edge_relax.ref import SCAN_BLOCK as b
+
+    e = elems[0].shape[-1]
+    lane = jnp.arange(e, dtype=jnp.int32) % b
+    d = 1
+    while d < min(b, e):
+        right = comb(tuple(a[..., :-d] for a in elems),
+                     tuple(a[..., d:] for a in elems))
+        keep = lane[d:] >= d
+        elems = tuple(
+            jnp.concatenate([a[..., :d], jnp.where(keep, r, a[..., d:])],
+                            axis=-1)
+            for a, r in zip(elems, right))
+        d *= 2
+    if e <= b:
+        return elems
+    nb = -(-e // b)
+    last = jnp.minimum(jnp.arange(nb, dtype=jnp.int32) * b + (b - 1), e - 1)
+    tot = _gather_carry_scan(comb, tuple(jnp.take(a, last, axis=-1)
+                                         for a in elems))
+    blk = jnp.arange(e, dtype=jnp.int32) // b
+    carry = tuple(jnp.take(t, jnp.maximum(blk - 1, 0), axis=-1) for t in tot)
+    folded = comb(carry, elems)
+    return tuple(jnp.where(blk >= 1, f, a) for f, a in zip(folded, elems))
+
+
+@pytest.mark.parametrize("e", [1, 300, 128 * 128 + 77, 3 * 128 * 128])
+def test_block_scan_carry_broadcast_is_bitwise(e):
+    """The broadcast carries give bitwise the floating sums of the
+    gathered carries (same fold, same order) at one, two and three
+    levels, laned, and the exact per-run counts."""
+    from repro.core.monoid import MONOIDS
+    from repro.kernels.edge_relax.ref import _block_scan, stream_scan
+
+    rng = np.random.default_rng(e)
+    key = jnp.asarray(np.sort(rng.integers(0, max(e // 50, 1), e)),
+                      jnp.int32)
+    cand = jnp.asarray(rng.standard_normal((3, e)), jnp.float32)
+    send = jnp.asarray(rng.random((3, e)) < 0.8)
+    v, c, _ = jax.jit(lambda *a: stream_scan(MONOIDS["sum"], *a, None))(
+        cand, send, key)
+
+    def comb(a, b):
+        return (jnp.where(b[2], b[0], a[0] + b[0]),
+                jnp.where(b[2], b[1], a[1] + b[1]), a[2] | b[2])
+
+    start = jnp.broadcast_to(
+        key != jnp.concatenate([jnp.full((1,), -2, jnp.int32), key[:-1]]),
+        cand.shape)
+    cnt = send.astype(jnp.int32)
+    rv, rc, _ = jax.jit(lambda *a: _gather_carry_scan(comb, a))(
+        cand, cnt, start)
+    nv, nc, _ = jax.jit(lambda *a: _block_scan(comb, a))(cand, cnt, start)
+    assert np.array_equal(np.asarray(v), np.asarray(rv))
+    assert np.array_equal(np.asarray(nv), np.asarray(rv))
+    assert np.array_equal(np.asarray(nc), np.asarray(rc))
+    # counts are exact: the inclusive per-run prefix count
+    k, s = np.asarray(key), np.asarray(send)
+    first = np.searchsorted(k, k, side="left")
+    csum = np.cumsum(s, axis=-1)
+    before = np.where(first > 0, csum[:, np.maximum(first - 1, 0)], 0)
+    assert np.array_equal(np.asarray(c), csum - before)

@@ -82,21 +82,27 @@ def _push_view(key, src, weight, dst_gid):
 
 @pytest.mark.parametrize("name", ["sssp", "cc", "pagerank"])
 def test_xla_pull_compiles(one_chip, name):
-    """Dense pull sweep on backend='xla': the flat segment path for the
-    min programs, the blocked segmented scan for the sum program."""
+    """Dense pull sweep on backend='xla': the blocked segmented scan for
+    every program, with the run layout computed once outside the sweep
+    (as the engines pass it) — on a delta-free stream it holds no
+    scatter."""
     prog = _prog(name)
+    run_end = jax.ShapeDtypeStruct((NP,), jnp.int32, sharding=one_chip)
 
-    def f(vs, se, gid, key, src, w, dg):
+    def f(vs, se, gid, key, src, w, dg, run_end):
         return edge_relax(prog, vs, se, gid, key, src, w, dg, n_keys=NP,
-                          block_e=BLOCK, backend="xla")
+                          block_e=BLOCK, backend="xla", run_end=run_end)
 
-    assert "tpu_custom_call" not in _compile(f, *_cell_args(prog, one_chip))
+    text = _compile(f, *_cell_args(prog, one_chip), run_end)
+    assert "tpu_custom_call" not in text
+    assert "scatter" not in text
 
 
 @pytest.mark.parametrize("name", ["sssp", "pagerank"])
 def test_xla_push_compiles(one_chip, name):
-    """Frontier-compacted push sweep on backend='xla' (flat for min, the
-    dense-stream reconstruction + scan for sum)."""
+    """Frontier-compacted push sweep on backend='xla' (the unsorted
+    segment combine for min, the dense-stream reconstruction + scan for
+    sum)."""
     prog = _prog(name)
 
     def f(vs, se, gid, key, src, w, dg):
