@@ -30,8 +30,9 @@ One chip, through the session's public entry points:
    session to close, so one graph layout is on the device at a time.
 
 Four chips (``--chips 4``): the same graph on ``engine="spmd"``, one cell
-per device — sssp, cc, one commit — bitwise against ``engine="sharded"``
-with 4 cells on device 0.
+per device — sssp, cc, sssp from a new source (which must compile
+nothing), one commit — bitwise against ``engine="sharded"`` with 4 cells
+on device 0; each phase prints every device's peak bytes.
 
 The persistent compilation cache is ``$JAX_COMPILATION_CACHE_DIR`` when
 that is set, else ``.jax_cache/`` next to this file.  Phase times are
@@ -80,16 +81,20 @@ def check(what: str, ok, detail: str = "") -> None:
 
 class Clock:
     """Per-phase wall time, with compile time (from jax.monitoring) and
-    the device's peak bytes reported beside it."""
+    the device's peak bytes reported beside it (every device's, where
+    there are several)."""
 
     def __init__(self, jax):
         self._jax = jax
         self._compile = 0.0
+        self.compiles = 0       # backend compiles (not traces, lowerings)
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
 
     def _on_event(self, event, duration, **_):
         if event in _COMPILE_EVENTS:
             self._compile += duration
+        if event == _COMPILE_EVENTS[-1]:
+            self.compiles += 1
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -97,10 +102,11 @@ class Clock:
         print(f"phase {name}: start", flush=True)
         yield
         wall = time.perf_counter() - t0
-        stats = self._jax.devices()[0].memory_stats() or {}
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", "n/a")
+                 for d in self._jax.devices()]
+        each = f" peak_bytes_by_device={peaks}" if len(peaks) > 1 else ""
         print(f"phase {name}: wall_s={wall} compile_s={self._compile - c0} "
-              f"peak_bytes_in_use={stats.get('peak_bytes_in_use', 'n/a')}",
-              flush=True)
+              f"peak_bytes_in_use={peaks[0]}{each}", flush=True)
 
 
 # ---------------------------------------------------------------------------
@@ -444,6 +450,7 @@ def run_four_chips(args, clock) -> None:
     with clock.phase("spmd-ingest"):
         src, dst, w, n = build_graph(args.scale, args.seed)
         root = pick_root(rng, src, n)
+        root2 = pick_root(rng, src, n)
         pairs = batch_pairs(args.scale)
         ops, _ = make_batch(rng, src, dst, w, n, pairs, pairs)
         part = partition(from_edges(src, dst, n, w,
@@ -461,6 +468,14 @@ def run_four_chips(args, clock) -> None:
         devs = {d for name, kw in (("sssp", {"source": root}), ("cc", {}))
                 for a in sess.vertex_state(name, **kw).values()
                 for d in a.sharding.device_set}
+        # a query from a new source compiles nothing on either engine
+        c0, t0 = clock.compiles, time.perf_counter()
+        out.append(sess.query("sssp", source=root2))
+        print(f"{engine}: second sssp source: wall_s="
+              f"{time.perf_counter() - t0} compiles={clock.compiles - c0}",
+              flush=True)
+        check(f"{engine} query from a new source compiles nothing",
+              clock.compiles == c0, f"(compiles={clock.compiles - c0})")
         stage(sess, ops)
         sess.commit()
         out += [sess.query("sssp", source=root), sess.query("cc")]
@@ -476,8 +491,9 @@ def run_four_chips(args, clock) -> None:
     with clock.phase("sharded-device-0"):
         sharded, devs0 = run("sharded")
         check("sharded state on device 0", devs0 == {jax.devices()[0]})
-    for tag, a, b in zip(("sssp", "cc", "sssp after commit",
-                          "cc after commit"), spmd, sharded):
+    for tag, a, b in zip(("sssp", "cc", "sssp from a new source",
+                          "sssp after commit", "cc after commit"), spmd,
+                         sharded):
         check(f"spmd == sharded {tag}",
               np.array_equal(np.asarray(a.values), np.asarray(b.values))
               and bool(a.stats.converged) and bool(b.stats.converged))

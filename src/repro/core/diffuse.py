@@ -46,6 +46,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .graph import DEFAULT_EDGE_BLOCK, ShardedGraph
@@ -77,6 +78,10 @@ __all__ = [
     "FRONTIER_LOG_CAP",
     "diffuse_spmd_step",
     "make_spmd_diffuse",
+    "spmd_exchange_bytes",
+    "cell_sharding",
+    "engine_views",
+    "on_mesh",
     "logical_view",
 ]
 
@@ -216,15 +221,56 @@ def _sg_as_dict(sg: ShardedGraph, with_push: bool = False):
         "gid": sg.gid,
         "out_degree": sg.out_degree,
     }
-    d.update(sg.csr_view())
-    es = d["csr_skey"].shape[-1] - sg.delta_width
-    n_keys = sg.n_shards * sg.n_per_shard
-    d["csr_run_end"] = jax.vmap(lambda k: run_ends(k, n_keys))(
-        d["csr_skey"][:, :es])
+    d.update(_pull_streams(sg))
     if with_push:
         d.update(sg.push_view())
     if sg.replica_members is not None:
         d["replica_members"] = sg.replica_members
+    return d
+
+
+def _pull_streams(sg: ShardedGraph) -> dict:
+    """:meth:`~.graph.ShardedGraph.csr_view` plus its run layout."""
+    d = sg.csr_view()
+    es = d["csr_skey"].shape[-1] - sg.delta_width
+    n_keys = sg.n_shards * sg.n_per_shard
+    d["csr_run_end"] = jax.vmap(lambda k: run_ends(k, n_keys))(
+        d["csr_skey"][:, :es])
+    return d
+
+
+@partial(jax.jit, static_argnames=("direction",))
+def _streams(sg: ShardedGraph, direction: str) -> dict:
+    """The computed half of :func:`_sg_as_dict`, as one program: the
+    gathered pull streams and run layout, or the gathered push streams."""
+    if direction == "push":
+        d = sg.push_view()
+        return {k: d[k] for k in ("push_key", "push_weight", "push_dst_gid")}
+    d = _pull_streams(sg)
+    del d["csr_skey"]
+    return d
+
+
+def engine_views(sg: ShardedGraph, pull: bool = True,
+                 push: bool = False) -> dict:
+    """:func:`_sg_as_dict` of a graph with both views, for callers that
+    keep it between dispatches (the session's spmd cells): the pull half
+    (vertex block, pull streams, run layout, replica table) and/or the
+    push half.  The gathers and the run layout run as one compiled
+    program (eagerly, the layout's binary search would dispatch op by
+    op); the fields ``_sg_as_dict`` passes through unchanged are the
+    graph's own arrays here, not copies."""
+    if sg.csr_perm is None or sg.push_perm is None:
+        raise ValueError("engine_views needs both views; call with_csr()")
+    d = {}
+    if pull:
+        d.update(node_ok=sg.node_ok, gid=sg.gid, out_degree=sg.out_degree)
+        d.update(_streams(sg, "pull"), csr_skey=sg.csr_key)
+        if sg.replica_members is not None:
+            d["replica_members"] = sg.replica_members
+    if push:
+        d.update(_streams(sg, "push"), push_src=sg.push_src,
+                 push_pos=sg.push_pos)
     return d
 
 
@@ -642,9 +688,12 @@ def diffuse_spmd_step(prog: VertexProgram, axis_name: str, n_shards: int,
 
     The returned fn takes per-device blocks of the ShardedGraph arrays
     (leading dim 1 = this device's shard, including the ``csr_*``/
-    ``push_*`` sorted edge streams) and runs rounds of (local relax ->
-    all_to_all operon exchange -> receive) until a psum'd quiescence check
-    fires.  The local while_loop has device-dependent trip count — cells
+    ``push_*`` sorted edge streams) and of the initial ``(vstate,
+    active)`` — computed outside, like :func:`initial_state` for the
+    logical engine, so the function holds no query parameter — and runs
+    rounds of (local relax -> all_to_all operon exchange -> receive)
+    until a psum'd quiescence check fires.  The local while_loop has
+    device-dependent trip count — cells
     genuinely run ahead of each other between exchanges.  The relaxation
     step dispatches to the same ``backend``/``sweep`` implementations as
     the logical engine; the direction selector runs *per device* on the
@@ -660,44 +709,21 @@ def diffuse_spmd_step(prog: VertexProgram, axis_name: str, n_shards: int,
     monoid = prog.monoid
     ident_f = lambda: monoid.identity(prog.msg_dtype)
 
-    def per_device(sgd):
-        import types as _types
-
+    def spmd_rounds(sgd, vstate0, active0):
         my_shard = lax.axis_index(axis_name).astype(jnp.int32)
         sgd = dict(sgd)
         # replicated [G, Rmax] member table (P() spec — no device axis)
         rmem = sgd.pop("replica_members", None)
         sg_s = {k: v[0] for k, v in sgd.items()}
+        vstate = jax.tree_util.tree_map(lambda a: a[0], vstate0)
+        active = active0[0]
 
+        member_mask = None
         if rmem is not None:
             member_mask, rsrc = _replica_maps(rmem, S, Np)
-            tot = S * Np
-            # logical init view for this device's row: node_ok counts each
-            # hub once; out_degree carries group totals (cross-device sum)
-            nonprim = (rsrc != jnp.arange(tot, dtype=jnp.int32)).reshape(
-                S, Np)
-            deg_all = lax.all_gather(sg_s["out_degree"], axis_name)
-            flatdeg = deg_all.reshape(tot)
-            valid = rmem >= 0
-            share = jnp.where(valid, flatdeg[jnp.clip(rmem, 0)], 0)
-            deg_log = flatdeg.at[jnp.where(valid, rmem, tot)].set(
-                jnp.broadcast_to(share.sum(axis=1)[:, None], rmem.shape
-                                 ).astype(flatdeg.dtype),
-                mode="drop").reshape(S, Np)
-            view_nok = sg_s["node_ok"] & ~jnp.take(nonprim, my_shard, axis=0)
-            view_deg = jnp.take(deg_log, my_shard, axis=0)
-        else:
-            member_mask = None
-            view_nok = sg_s["node_ok"]
-            view_deg = sg_s["out_degree"]
-
-        # init needs [S, Np]-shaped thinking; emulate with this shard's block
-        view = _types.SimpleNamespace(gid=sg_s["gid"], node_ok=view_nok,
-                                      out_degree=view_deg)
-        vstate, active = prog.init(view)
-        if rmem is not None:
-            # entry broadcast: mirror primary init state over member slots
-            # (members may live on other devices — gather, map, re-slice)
+            # entry broadcast: callers only maintain primary slots — mirror
+            # them over the members (members may live on other devices —
+            # gather, map, re-slice)
             def _bcast_row(x):
                 full = _broadcast_from_primary(
                     lax.all_gather(x, axis_name), rsrc, S, Np)
@@ -856,7 +882,51 @@ def diffuse_spmd_step(prog: VertexProgram, axis_name: str, n_shards: int,
             )
         return vfinal, stats
 
-    return per_device
+    return spmd_rounds
+
+
+def cell_sharding(mesh, key: str, axis_name: str = "cells"):
+    """Where an engine-facing array lives on the mesh: one cell per
+    device (``P(axis_name)`` on the leading axis), except the [G, Rmax]
+    replica member table, which every device holds whole."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return NamedSharding(mesh, P() if key == "replica_members"
+                         else P(axis_name))
+
+
+def on_mesh(mesh, arrays: dict, axis_name: str = "cells") -> dict:
+    """``device_put`` a dict of [S, ...] arrays (a graph's
+    :meth:`~.graph.ShardedGraph.state_dict`, its engine-facing views, or
+    a vertex state) one cell per device, by :func:`cell_sharding`.  An
+    array already placed so is returned as it is.  An array of another
+    platform (a graph built on the host's CPU device) is cut into cells
+    in host memory: ``device_put`` would slice it on this platform's
+    default device, which would then hold the whole array."""
+    platform = mesh.devices.flat[0].platform
+
+    def host_or_self(v):
+        if isinstance(v, jax.Array) and any(
+                d.platform != platform for d in v.sharding.device_set):
+            return np.asarray(v)
+        return v
+
+    return {k: jax.device_put(host_or_self(v),
+                              cell_sharding(mesh, k, axis_name))
+            for k, v in arrays.items()}
+
+
+def spmd_exchange_bytes(prog: VertexProgram, n_shards: int,
+                        n_per_shard: int) -> int:
+    """Bytes one round's ``all_to_all`` exchange of the spmd engine sends
+    between devices, summed over the devices: each device sends its
+    outbox rows for the other ``n_shards - 1`` cells, ``n_per_shard``
+    slots per row (and lane), each slot a value, a one-byte has flag and
+    an int32 payload for payload programs.  From shapes alone."""
+    slot = (jnp.dtype(prog.msg_dtype).itemsize + 1
+            + (4 if prog.with_payload else 0))
+    return (n_shards * (n_shards - 1) * n_per_shard * (prog.lanes or 1)
+            * slot)
 
 
 def make_spmd_diffuse(mesh, prog: VertexProgram, sg_template,
@@ -865,20 +935,25 @@ def make_spmd_diffuse(mesh, prog: VertexProgram, sg_template,
                       block_e: int | None = None, sweep: str = "pull",
                       push_threshold: float = DEFAULT_PUSH_THRESHOLD,
                       delta_blocks: int | None = None):
-    """Wrap the per-device engine in shard_map over ``axis_name``.
+    """Wrap the per-device engine in shard_map over ``axis_name``, jitted.
 
     ``sg_template`` may be a ShardedGraph or a dict of (ShapeDtypeStruct)
-    arrays matching :func:`_sg_as_dict` — the latter is what the dry-run
-    uses; dict templates must carry the ``csr_*`` and ``push_*`` stream
+    arrays matching :func:`_sg_as_dict` — the latter is what the session
+    (its placed views) and the dry-run pass; dict templates must carry
+    the ``csr_*`` (and for push/auto sweeps the ``push_*``) stream
     fields, padded to a multiple of ``block_e`` (pass it when the streams
     were built with a non-default :meth:`ShardedGraph.with_csr` block).
     ``delta_blocks`` is the staged-delta capacity baked into the streams
     (taken from a ShardedGraph template automatically; dict templates
     default to 0 = delta-free).
-    Returns a function (sgd dict) -> (vertex_state [S, Np] layout, stats).
-    """
-    import types as _types
 
+    Returns a jitted function ``(sgd, vstate0, active0) -> (vertex_state,
+    stats)``: the graph dict, the initial state from
+    :func:`initial_state` and the vertex state out all in [S, Np] layout
+    ([S, L, Np] for laned programs), one cell per device.  The program's
+    ``init`` never enters the trace, so one compile serves every query
+    of the same program structure (any source) at the same shapes.
+    """
     from jax.sharding import PartitionSpec as P
 
     if isinstance(sg_template, ShardedGraph):
@@ -901,34 +976,22 @@ def make_spmd_diffuse(mesh, prog: VertexProgram, sg_template,
     S = sgd_t["gid"].shape[0]
     Np = sgd_t["gid"].shape[1]
 
-    per_device = diffuse_spmd_step(
+    spmd_rounds = diffuse_spmd_step(
         prog, axis_name, S, Np, max_local_iters, max_rounds,
         block_e=block_e, backend=backend, sweep=sweep,
         push_threshold=push_threshold, delta_e=delta_blocks * block_e,
     )
-
-    # Derive the vertex-state pytree structure from prog.init (shape-only).
-    def _init_struct(gid, node_ok, out_degree):
-        view = _types.SimpleNamespace(
-            gid=gid, node_ok=node_ok, out_degree=out_degree
-        )
-        return prog.init(view)
-
-    vstate_struct, _ = jax.eval_shape(
-        _init_struct, sgd_t["gid"], sgd_t["node_ok"], sgd_t["out_degree"]
-    )
-    # graph arrays shard one cell per device; the [G, Rmax] replica member
-    # table (when present) is replicated — every device needs every group
-    in_specs = ({k: (P() if k == "replica_members" else P(axis_name))
-                 for k in sgd_t},)
-    out_specs = (
-        jax.tree_util.tree_map(lambda _: P(axis_name), vstate_struct),
-        DiffuseStats(*[P()] * len(DiffuseStats._fields)),
-    )
-    return jax.shard_map(
-        per_device,
+    # graph arrays and vertex state shard one cell per device; the
+    # [G, Rmax] replica member table (when present) is replicated — every
+    # device needs every group
+    cells = P(axis_name)
+    in_specs = ({k: cell_sharding(mesh, k, axis_name).spec for k in sgd_t},
+                cells, cells)
+    out_specs = (cells, DiffuseStats(*[P()] * len(DiffuseStats._fields)))
+    return jax.jit(jax.shard_map(
+        spmd_rounds,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
         check_vma=False,
-    )
+    ))

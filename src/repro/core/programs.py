@@ -108,8 +108,8 @@ class VertexProgram:
     ``(vstate, active)`` as *traced* inputs, so programs differing only
     in their init closure (``sssp(source=0)`` vs ``sssp(source=1)``)
     share one compiled fixed-point loop instead of retracing per
-    source.  Callers that do trace ``init`` (the spmd engine, the laned
-    stacker) must key their caches on ``_fn_key(prog.init)`` as well.
+    source.  Callers that do trace ``init`` (the laned stacker) must key
+    their caches on ``_fn_key(prog.init)`` as well.
     """
 
     monoid: Monoid                 # first-class combine (min/max/sum class)

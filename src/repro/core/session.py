@@ -35,7 +35,9 @@ from-scratch fixed point exactly):
 
 Engine matrix (DESIGN.md §2.5): ``sharded`` is the bulk-asynchronous
 logical engine (default, any program); ``spmd`` shard_maps one compute
-cell per mesh device (any program, needs >= n_cells devices); ``event``
+cell per mesh device (any program, needs >= n_cells devices; a session
+built with ``engine="spmd"`` keeps every cell's arrays on its own
+device, placed again after each commit or compaction); ``event``
 is the message-at-a-time host oracle with real Dijkstra–Scholten
 termination — a generic interpreter runs any registered program, with
 handwritten fast oracles for SSSP/BFS.
@@ -58,6 +60,7 @@ to the push sweep.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -72,13 +75,15 @@ from . import chaos
 from . import trace
 from .diffuse import (
     DiffuseStats,
-    _sg_as_dict,
     diffuse,
     diffuse_from,
+    engine_views,
     exact_streams_for,
     initial_state,
     logical_view,
     make_spmd_diffuse,
+    on_mesh,
+    spmd_exchange_bytes,
 )
 from .dynamic import NameServer, _invalidate_subtrees
 from .graph import ShardedGraph, from_edges
@@ -91,7 +96,6 @@ from .programs import (
     ProgramHandle,
     ProgramSpec,
     VertexProgram,
-    _fn_key,
     freeze_kwargs,
     make_laned,
     register_program,
@@ -122,6 +126,16 @@ def _json_np(o):
     if isinstance(o, np.generic):
         return o.item()
     raise TypeError(f"not JSON-serializable: {type(o).__name__}")
+
+
+def _host_build():
+    """Build arrays on the host's CPU device when the process has one
+    besides its accelerators, so that no accelerator holds what is built
+    there; a no-op otherwise."""
+    try:
+        return jax.default_device(jax.devices("cpu")[0])
+    except RuntimeError:
+        return contextlib.nullcontext()
 
 
 class ConvergenceError(RuntimeError):
@@ -255,12 +269,20 @@ class DiffusionSession:
         self._cache: dict[tuple, _Entry] = {}
         self._pending: UpdateBatch | None = None
         self._spmd_fns: dict = {}
+        # spmd engine: one mesh per session, and the engine-facing views
+        # of ``_views_of`` (a graph version) kept on it between dispatches
+        self._mesh = None
+        self._views: dict | None = None
+        self._views_of: ShardedGraph | None = None
         # durability (DESIGN.md §2.13): armed by save()/open()
         self._dur_dir: str | None = None
         self._ckpt = None                       # CheckpointManager
         self._journal: UpdateJournal | None = None
         self._journal_fsync = journal_fsync
         self._snapshot_keep = snapshot_keep
+        if engine == "spmd":
+            self._spmd_mesh()
+            self._set_graph(part.sg)
 
     # ------------------------------------------------------------------
     # construction
@@ -278,11 +300,16 @@ class DiffusionSession:
         ``edge_slack`` / ``node_slack`` reserve free capacity slots per
         cell for the dynamic primitives (paper §VI).
         ``replica_threshold`` enables skew-aware hub splitting
-        (rhizomes, DESIGN.md §2.12): ``"auto"`` or an int degree bound."""
-        g = from_edges(src, dst, n_nodes, weight,
-                       edge_slack=edge_slack, node_slack=node_slack)
-        part = partition(g, n_cells, strategy=strategy,
-                         replica_threshold=replica_threshold)
+        (rhizomes, DESIGN.md §2.12): ``"auto"`` or an int degree bound.
+
+        With ``engine="spmd"`` the graph is built on the host's CPU device
+        and then placed one cell per accelerator, so no accelerator ever
+        holds the whole graph."""
+        with _host_build() if engine == "spmd" else contextlib.nullcontext():
+            g = from_edges(src, dst, n_nodes, weight,
+                           edge_slack=edge_slack, node_slack=node_slack)
+            part = partition(g, n_cells, strategy=strategy,
+                             replica_threshold=replica_threshold)
         return cls(part, engine=engine, **kw)
 
     # ------------------------------------------------------------------
@@ -575,6 +602,8 @@ class DiffusionSession:
         self._cache_put(key, entry)
         with trace.stage(trace.QUERY_BUDGET):
             self._enforce_budget(stats, f"query {name!r}")
+            if engine == "spmd":
+                self._count_exchange(program, stats)
         with trace.stage(trace.QUERY_RESULT):
             res = self._result(entry)
             self._maybe_validate(entry, res, validate, f"query {name!r}")
@@ -588,44 +617,103 @@ class DiffusionSession:
         clean graph instead of re-sorting per call; min/max programs
         consume the incremental views directly and come back unchanged."""
         if program is not None:
-            self.part.sg = exact_streams_for(self.sg, program)
+            sg = exact_streams_for(self.sg, program)
+            if sg is not self.sg:
+                self._set_graph(sg)
+
+    # -- the spmd engine's mesh and chip-resident cells ----------------------
+
+    def _spmd_mesh(self):
+        """The session's mesh, one compute cell per device (built once)."""
+        if self._mesh is None:
+            from ..launch.mesh import make_mesh
+
+            S, devices = self.n_cells, jax.devices()
+            if len(devices) < S:
+                raise RuntimeError(
+                    f"engine='spmd' needs >= {S} devices (one per compute "
+                    f"cell); this process has {len(devices)}. Set "
+                    f"XLA_FLAGS=--xla_force_host_platform_device_count="
+                    f"{S} before importing jax, or use engine='sharded'.")
+            self._mesh = make_mesh((S,), ("cells",), devices=devices[:S])
+        return self._mesh
+
+    def _set_graph(self, sg: ShardedGraph) -> None:
+        """Adopt a new graph version (construction, commit, compaction).
+        On an ``engine="spmd"`` session it is placed one cell per device,
+        with its pull views, in the host span ``repro.place``: every
+        array of cell s lives on device s from here on, so a dispatch
+        moves no edge bytes between chips."""
+        if self.engine != "spmd":
+            self.part.sg = sg
+            return
+        with trace.stage(trace.PLACE):
+            self.part.sg = dataclasses.replace(
+                sg, **on_mesh(self._spmd_mesh(), sg.state_dict()))
+            self._place_pull_views()
+
+    def _place_pull_views(self) -> None:
+        self._views = on_mesh(self._spmd_mesh(), engine_views(self.sg))
+        self._views_of = self.sg
+
+    def _spmd_views(self, with_push: bool) -> dict:
+        """The engine-facing dict of the current graph on the mesh, built
+        once per graph version; the push streams join it the first time
+        a push or auto sweep needs them (each build a ``repro.place``)."""
+        if self._views_of is not self.sg:
+            with trace.stage(trace.PLACE):
+                self._place_pull_views()
+        if with_push and "push_src" not in self._views:
+            with trace.stage(trace.PLACE):
+                self._views = dict(self._views, **on_mesh(
+                    self._spmd_mesh(),
+                    engine_views(self.sg, pull=False, push=True)))
+        if with_push:
+            return self._views
+        return {k: v for k, v in self._views.items()
+                if not k.startswith("push_")}
 
     def _prepare_diffusion(self, program: VertexProgram, engine: str,
-                           backend: str, delta, sweep: str = "pull"):
-        """Compact, build the initial state (sharded) or the shard_map
-        function (spmd), and return the device call that runs the
+                           backend: str, delta, sweep: str = "pull",
+                           state=None):
+        """Compact, build the initial state (or take ``state``, a
+        ``(vstate, active)`` to resume from) and, on spmd, the jitted
+        shard_map function, and return the device call that runs the
         diffusion: ``() -> (vstate, stats)``."""
         self._compact_for(program)
         sg = self.sg
+        vstate0, active0 = (initial_state(sg, program) if state is None
+                            else state)
         if engine == "sharded":
-            vstate0, active0 = initial_state(sg, program)
             return lambda: diffuse_from(
                 sg, program, vstate0, active0,
                 max_local_iters=self.max_local_iters,
                 max_rounds=self.max_rounds, delta=delta, backend=backend,
                 sweep=sweep)
-        S = self.n_cells
-        if len(jax.devices()) < S:
-            raise RuntimeError(
-                f"engine='spmd' needs >= {S} devices (one per compute "
-                f"cell); this process has {len(jax.devices())}. Set "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count={S} "
-                f"before importing jax, or use engine='sharded'.")
-        # the per-device fn traces prog.init *inside* shard_map, so the
-        # cache key needs the init identity on top of the program's
-        # (init-excluding) structural equality — see VertexProgram.__eq__
-        fkey = (program, _fn_key(program.init), S, backend, sweep)
-        if fkey not in self._spmd_fns:
-            from ..launch.mesh import make_mesh
-
-            mesh = make_mesh((S,), ("cells",))
-            self._spmd_fns[fkey] = make_spmd_diffuse(
-                mesh, program, sg, axis_name="cells",
+        mesh = self._spmd_mesh()
+        sgd = self._spmd_views(with_push=sweep != "pull")
+        # one function per program structure: the initial state enters as
+        # a sharded input, so every source of a program shares its compile
+        fkey = (program, backend, sweep, sg.csr_block, sg.delta_blocks)
+        fn = self._spmd_fns.get(fkey)
+        if fn is None:
+            fn = self._spmd_fns[fkey] = make_spmd_diffuse(
+                mesh, program, sgd, axis_name="cells",
                 max_local_iters=self.max_local_iters,
-                max_rounds=self.max_rounds, backend=backend, sweep=sweep)
-        fn = self._spmd_fns[fkey]
-        sgd = _sg_as_dict(sg, with_push=sweep != "pull")
-        return lambda: fn(sgd)
+                max_rounds=self.max_rounds, backend=backend,
+                block_e=sg.csr_block, sweep=sweep,
+                delta_blocks=max(sg.delta_blocks, 0))
+        placed = on_mesh(mesh, {"vstate": vstate0, "active": active0})
+        return lambda: fn(sgd, placed["vstate"], placed["active"])
+
+    def _count_exchange(self, program: VertexProgram, stats) -> None:
+        """The bytes an spmd diffusion's exchange sent between devices:
+        ``DiffuseStats.rounds`` x :func:`~.diffuse.spmd_exchange_bytes`,
+        as the scalar event ``/repro/exchange_bytes``."""
+        rounds = int(trace.to_host(stats.rounds, "exchange"))
+        jax.monitoring.record_scalar(
+            trace.EXCHANGE_BYTES, rounds * spmd_exchange_bytes(
+                program, self.n_cells, self.sg.n_per_shard))
 
     def _query_lanes(self, spec: ProgramSpec, name: str, lane_vals: list,
                      kwargs: dict, engine: str, backend: str,
@@ -665,6 +753,8 @@ class DiffusionSession:
         with trace.stage(trace.QUERY_BUDGET):
             self._enforce_budget(stats, f"query {name!r} "
                                         f"({len(lane_vals)} lanes)")
+            if engine == "spmd":
+                self._count_exchange(laned, stats)
 
         vk = value_key or spec.value_key
         results = []
@@ -723,9 +813,11 @@ class DiffusionSession:
         return entry.vstate
 
     def _run_spmd(self, program: VertexProgram, backend: str = "xla",
-                  sweep: str = "pull"):
-        return self._prepare_diffusion(program, "spmd", backend, None,
-                                       sweep)()
+                  sweep: str = "pull", state=None):
+        vstate, stats = self._prepare_diffusion(program, "spmd", backend,
+                                                None, sweep, state)()
+        self._count_exchange(program, stats)
+        return vstate, stats
 
     def _result(self, entry: _Entry) -> Result:
         values = self.to_global(entry.vstate[entry.value_key])
@@ -846,7 +938,8 @@ class DiffusionSession:
                 chaos.point("commit.journal-appended")
             try:
                 with trace.stage(trace.COMMIT_APPLY):
-                    self.part.sg, applied = self._pending.apply(self.part.sg)
+                    sg, applied = self._pending.apply(self.part.sg)
+                self._set_graph(sg)
             except Exception:
                 # the store rejected the batch — un-journal it (ChaosKill
                 # is a BaseException and deliberately escapes this)
@@ -915,17 +1008,22 @@ class DiffusionSession:
             return stats
 
         vstate, active = self._warm_state(entry, applied, strategy)
-        # resume under the entry's own delta gate + kernel backend, so the
-        # repair diffusion is work-gated exactly like the original query.
-        # Warm repairs resume from a tiny frontier, so they default to the
-        # frontier-compacted push sweep (an explicit query sweep wins) —
-        # bitwise-identical, O(frontier-adjacent edges) per round.
-        vstate, stats = diffuse_from(sg, entry.prog, vstate, active,
-                                     max_local_iters=mli,
-                                     max_rounds=self.max_rounds,
-                                     delta=entry.delta,
-                                     backend=entry.backend,
-                                     sweep=entry.sweep or "push")
+        # resume under the entry's own engine, delta gate + kernel backend,
+        # so the repair diffusion is work-gated exactly like the original
+        # query.  Warm repairs resume from a tiny frontier, so they default
+        # to the frontier-compacted push sweep (an explicit query sweep
+        # wins) — bitwise-identical, O(frontier-adjacent edges) per round.
+        if entry.engine == "spmd":
+            vstate, stats = self._run_spmd(entry.prog, entry.backend,
+                                           entry.sweep or "push",
+                                           (vstate, active))
+        else:
+            vstate, stats = diffuse_from(sg, entry.prog, vstate, active,
+                                         max_local_iters=mli,
+                                         max_rounds=self.max_rounds,
+                                         delta=entry.delta,
+                                         backend=entry.backend,
+                                         sweep=entry.sweep or "push")
         entry.vstate, entry.stats = vstate, stats
         return stats
 

@@ -13,6 +13,9 @@ against queries of milliseconds to seconds:
   the host: ``jax.device_get`` (explicit, so legal under the sanitizer's
   transfer guard) plus the ``jax.monitoring`` scalar event
   :data:`HOST_READ_BYTES` carrying the bytes read and a ``site`` tag.
+* Each spmd dispatch records the bytes its exchange sent between
+  devices as the scalar event :data:`EXCHANGE_BYTES`; placing a graph
+  version on the mesh is the stage :data:`PLACE`.
 * The diffusion loop's ``jax.named_scope`` names (:data:`SCOPES`) set
   only op metadata: a device trace or a compiled module attributes each
   device op to the relax sweep, the local apply, the round-end exchange
@@ -47,9 +50,17 @@ COMMIT_APPLY = "repro.commit.apply"      # the batched update scatter
 COMMIT_REPAIR = "repro.commit.repair"    # + "." + strategy, per cache entry
 COMMIT_COMPACT = "repro.commit.compact"  # a restart repair's compaction
 
+PLACE = "repro.place"    # spmd: a graph version's cells onto the mesh
+
 # -- host reads (jax.monitoring scalar event, value = bytes, tag site=) ----
 
 HOST_READ_BYTES = EVENT_PREFIX + "host_read_bytes"
+
+# -- exchange bytes (jax.monitoring scalar event, value = bytes) -----------
+
+# per spmd dispatch: rounds x the bytes one round's all_to_all sends
+# between devices, from shapes (diffuse.spmd_exchange_bytes)
+EXCHANGE_BYTES = EVENT_PREFIX + "exchange_bytes"
 
 # -- device scopes (jax.named_scope, in each op's op_name metadata) --------
 

@@ -19,6 +19,7 @@ collective schedule on real hardware meshes.
 
 import argparse   # noqa: E402
 import json       # noqa: E402
+import types      # noqa: E402
 
 import jax        # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -89,7 +90,11 @@ def main():
     fn = make_spmd_diffuse(mesh, prog, sgd, axis_name="cells",
                            max_local_iters=args.max_local_iters,
                            sweep=args.sweep)
-    lowered = jax.jit(fn).lower(sgd)
+    # the initial state as the session passes it: [S, Np] per field
+    vstate0, active0 = jax.eval_shape(lambda d: prog.init(
+        types.SimpleNamespace(gid=d["gid"], node_ok=d["node_ok"],
+                              out_degree=d["out_degree"])), sgd)
+    lowered = fn.lower(sgd, vstate0, active0)
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     print("memory_analysis:",

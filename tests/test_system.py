@@ -10,7 +10,8 @@ from repro.core import (
     personalized_pagerank,
     sssp,
 )
-from repro.core.diffuse import _sg_as_dict, diffuse, make_spmd_diffuse
+from repro.core.diffuse import (_sg_as_dict, diffuse, initial_state,
+                                make_spmd_diffuse)
 from repro.core.event import build_adjacency, event_sssp
 from repro.core.generators import GENERATORS, make_graph_family
 from repro.core.programs import sssp_program
@@ -120,8 +121,9 @@ def test_spmd_engine_matches_logical_engine():
     src, dst, w, n = make_graph_family("erdos_renyi", 120, seed=9)
     part = build(src, dst, n, w, n_cells=1)
     mesh = make_mesh((1,), ("cells",))
-    fn = make_spmd_diffuse(mesh, sssp_program(3), part.sg, axis_name="cells")
-    vs, st = fn(_sg_as_dict(part.sg))
+    prog = sssp_program(3)
+    fn = make_spmd_diffuse(mesh, prog, part.sg, axis_name="cells")
+    vs, st = fn(_sg_as_dict(part.sg), *initial_state(part.sg, prog))
     ref = sssp(part, 3)
     got = np.asarray(part.to_global_layout(vs["dist"]))[: part.n_real]
     assert _dist_close(got, ref.values)

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import collections
 import glob
+import json
 import os
 import re
 import subprocess
@@ -265,7 +266,8 @@ def test_spmd_step_hlo_carries_all_four_scopes_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax
-        from repro.core.diffuse import _sg_as_dict, make_spmd_diffuse
+        from repro.core.diffuse import (_sg_as_dict, initial_state,
+                                        make_spmd_diffuse)
         from repro.core.generators import make_graph_family
         from repro.core.programs import PROGRAMS
         from repro.core.session import DiffusionSession
@@ -275,7 +277,8 @@ def test_spmd_step_hlo_carries_all_four_scopes_subprocess():
         sess = DiffusionSession.from_edges(src, dst, n, w, n_cells=4)
         prog = PROGRAMS["sssp"].factory(source=0)
         fn = make_spmd_diffuse(make_mesh((4,), ("cells",)), prog, sess.sg)
-        print(jax.jit(fn).lower(_sg_as_dict(sess.sg)).compile().as_text())
+        print(fn.lower(_sg_as_dict(sess.sg), *initial_state(sess.sg, prog))
+              .compile().as_text())
     """)
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(ROOT, "src"))
@@ -319,3 +322,85 @@ def test_commit_with_deletes_emits_its_stages_and_reads(tmp_path):
     assert sites["repair.parents"] == len(dels)
     assert sites["repair.component"] == 2 * len(dels)
     sess.close()
+
+
+# ---------------------------------------------------------------------------
+# spmd: placing a graph version, and the bytes of the exchange
+# ---------------------------------------------------------------------------
+
+_SPMD_TRACE = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax
+    import numpy as np
+    from repro.core import trace
+    from repro.core.generators import make_graph_family
+    from repro.core.programs import PROGRAMS
+    from repro.core.session import DiffusionSession
+
+    places, xbytes = [], []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda ev, s, **kw: places.append(ev)
+        if ev == trace.EVENT_PREFIX + trace.PLACE else None)
+    jax.monitoring.register_scalar_listener(
+        lambda ev, v, **kw: xbytes.append(v)
+        if ev == trace.EXCHANGE_BYTES else None)
+
+    src, dst, w, n = make_graph_family("graph500", 256, seed=1)
+    sess = DiffusionSession.from_edges(src, dst, n, w, engine="spmd",
+                                       sweep="pull", edge_slack=0.2)
+    out = {"built": len(places), "np": sess.sg.n_per_shard, "queries": []}
+    for name, kw in (("sssp", {"source": 0}), ("bfs", {"source": 3}),
+                     ("sssp", {"source": 5})):
+        k = len(xbytes)
+        res = sess.query(name, sweep="pull", **kw)
+        prog = PROGRAMS[name].factory(**kw)
+        out["queries"].append({
+            "name": name, "rounds": int(res.stats.rounds),
+            "events": xbytes[k:],
+            "slot": int(np.dtype(prog.msg_dtype).itemsize) + 1
+            + 4 * (prog.payload is not None)})
+    out["queried"] = len(places)
+    sess.delete_edge(int(src[0]), int(dst[0]))
+    sess.add_edge(0, 7, 0.5)
+    sess.commit()
+    out["committed"] = len(places)
+    sess.query("ppr", source=0, eps=1e-4, sweep="pull")   # compacts first
+    out["compacted"] = len(places)
+    sess.query("ppr", source=1, eps=1e-4, sweep="pull")
+    out["clean"] = len(places)
+    print("SPMD_TRACE " + json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def spmd_trace():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _SPMD_TRACE],
+                         capture_output=True, text=True, env=env, cwd=ROOT,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    line = [x for x in out.stdout.splitlines() if x.startswith("SPMD_TRACE ")]
+    assert line, out.stderr[-4000:]
+    return json.loads(line[0].split(" ", 1)[1])
+
+
+def test_spmd_place_span_once_per_compaction(spmd_trace):
+    """Construction, a commit and a sum program's compaction each place
+    the new graph version once; queries on a placed version place
+    nothing."""
+    t = spmd_trace
+    assert (t["built"], t["queried"], t["committed"], t["compacted"],
+            t["clean"]) == (1, 1, 2, 3, 3)
+
+
+def test_spmd_exchange_bytes_are_rounds_times_shapes(spmd_trace):
+    """One event per dispatch: rounds x 4 devices x 3 peers x Np slots x
+    (value + has flag + payload)."""
+    np_ = spmd_trace["np"]
+    for q in spmd_trace["queries"]:
+        assert q["events"] == [q["rounds"] * 4 * 3 * np_ * q["slot"]], q
+    assert spmd_trace["queries"][0]["slot"] == 4 + 1 + 4   # dist + parent

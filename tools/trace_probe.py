@@ -1,30 +1,36 @@
 #!/usr/bin/env python3
-"""Read the program's spans, scopes and host reads on the query cell's
+"""Read the program's spans, scopes and host reads on a query cell's
 graph, on the chip, and print what share of the device time each
-``diffuse.*`` scope takes.
+``diffuse.*`` scope takes, on each device.
 
-    python3 tools/trace_probe.py [--scale 16] [--seed N] [--keys 3] [--out F]
+    python3 tools/trace_probe.py [--config graph500-s16] [--scale N]
+                                 [--seed N] [--keys 3] [--out F]
 
-It builds the session of ``bench/configs/graph500-s16.json`` (the graph
-from its ``graph_seed``, ids relabeled by ``--seed``), warms bfs and sssp
-up, then runs bfs and sssp from ``--keys`` search keys twice: untraced,
-with per-stage host seconds and host reads from a ``jax.monitoring``
-listener, and under the profiler, each query inside a ``bench.query``
-span as the benchmark's loop makes them.
+It builds the session of ``bench/configs/<config>.json`` (the graph
+from its ``graph_seed``, ids relabeled by ``--seed``, on the engine it
+names: ``graph500-s18-spmd`` runs one cell per chip on four), warms bfs
+and sssp up, then runs bfs and sssp from ``--keys`` search keys twice:
+untraced, with per-stage host seconds, host reads, compiles, the
+``repro.place`` stages and the ``/repro/exchange_bytes`` counter from a
+``jax.monitoring`` listener, and under the profiler, each query inside a
+``bench.query`` span as the benchmark's loop makes them.
 
 The chip's ``XLA Ops`` events carry no op metadata, so a device op's
-scope comes from the compiled HLO of ``_run_rounds``: each instruction's
+scope comes from the compiled HLO of the diffusion (``_run_rounds``, or
+the spmd engine's ``spmd_rounds``): each instruction's
 ``op_name`` holds the ``jax.named_scope`` path, and its innermost
 ``diffuse.*`` name is the op's scope.  The module is compiled afresh with
 the persistent cache off (an executable loaded from the cache prints no
 metadata).  The compiler leaves some fusions without metadata (the
 segment-combine scatters); such a fusion takes the commonest scope of
 the instructions in the computations it calls, nested fusions included.
-Device ops outside a ``_run_rounds`` module (eager init, per-call
-gathers) count as ``unscoped``.
+Device ops outside that module (eager init, per-call gathers) count as
+``unscoped``.  On several devices a collective's self time is the
+device's share of the exchange, waits at the barrier included.
 
 It prints, and writes as JSON to ``--out``: the share of device self
-time per scope inside ``repro.query``; per query the device-idle time
+time per scope inside ``repro.query``, over all devices and per device;
+each device's peak memory; per query the device-idle time
 inside each ``repro.query.*`` stage; relax time per sub-iteration,
 exchange time per round, host reads per query; the top ops as
 ``<scope>/<hlo name>``; and the longest idle gaps of the traced window,
@@ -53,10 +59,13 @@ from bench.lib import graph as G  # noqa: E402
 from bench.lib import measure as M  # noqa: E402
 from repro.core import trace  # noqa: E402
 from repro.core.diffuse import _run_rounds, initial_state  # noqa: E402
+from bench.lib.exchange import is_collective  # noqa: E402
 from repro.core.programs import PROGRAMS  # noqa: E402
 from repro.core.session import DiffusionSession  # noqa: E402
 
 PROGS = ("bfs", "sssp")
+MODULES = ("jit__run_rounds", "jit_spmd_rounds")
+COMPILE_EVENT = M.COMPILE_EVENT
 _SCOPE_RE = re.compile("|".join(re.escape(s) for s in trace.SCOPES))
 
 
@@ -70,16 +79,18 @@ def load_json(path):
         return json.load(f)
 
 
-def build(scale: int, seed: int, n_keys: int):
-    cfg = load_json(os.path.join(ROOT, "bench/configs/graph500-s16.json"))
-    cfg["generator"]["scale"] = scale
+def build(config: str, scale: int | None, seed: int, n_keys: int):
+    cfg = load_json(os.path.join(ROOT, "bench/configs", f"{config}.json"))
+    if scale is not None:
+        cfg["generator"]["scale"] = scale
     g0 = G.graph500(cfg["generator"], int(cfg["graph_seed"]))
     g, lab = G.relabel_within_cells(g0, int(cfg["relabel_blocks"]), seed)
     keys0, warm = G.search_keys(g0, int(cfg["search_keys"]),
                                 int(cfg["graph_seed"]))
     keys = [int(lab[k]) for k in keys0[:2 * n_keys]]
     sess = DiffusionSession.from_edges(g.src, g.dst, g.n, g.w,
-                                       engine="sharded", edge_slack=0.0)
+                                       engine=cfg["engine"],
+                                       edge_slack=float(cfg["edge_slack"]))
     lone = np.flatnonzero(np.bincount(g.src, minlength=g.n) == 0)
     root = int(lone[0]) if lone.size else int(lab[warm])
     for p in PROGS:
@@ -100,18 +111,26 @@ def run_queries(sess, plan, events, annotate: bool):
         rows.append({"prog": p, "wall": time.perf_counter() - t0,
                      "stages": [e for e in events if e[0] == "dur"],
                      "reads": sum(e[0] == "read" for e in events),
+                     "compiles": sum(e[0] == "compile" for e in events),
+                     "exchange_bytes": sum(e[2] for e in events
+                                           if e[0] == "exchange"),
                      "rounds": int(r.stats.rounds),
                      "local_iters": int(r.stats.local_iters)})
     return rows
 
 
 def hlo_scopes(sess, prog_name: str, key: int) -> dict:
-    """HLO instruction name -> scope of ``_run_rounds`` for one program."""
+    """HLO instruction name -> scope of the diffusion for one program."""
     prog = PROGRAMS[prog_name].factory(source=key)
     v0, a0 = initial_state(sess.sg, prog)
-    txt = _run_rounds.lower(sess.sg, prog, v0, a0, sess.max_local_iters,
-                            sess.max_rounds, None, sess.backend,
-                            sess.sweep).compile().as_text()
+    if sess.engine == "spmd":
+        fn = next(f for k, f in sess._spmd_fns.items() if k[0] == prog)
+        txt = fn.lower(sess._spmd_views(sess.sweep != "pull"), v0,
+                       a0).compile().as_text()
+    else:
+        txt = _run_rounds.lower(sess.sg, prog, v0, a0, sess.max_local_iters,
+                                sess.max_rounds, None, sess.backend,
+                                sess.sweep).compile().as_text()
     comp_of, op_of, calls = {}, {}, {}
     comp_ops = collections.defaultdict(list)
     cur = None
@@ -157,20 +176,24 @@ def hlo_scopes(sess, prog_name: str, key: int) -> dict:
 
 
 def read_events(log_dir: str):
-    """(device ops, _run_rounds module events, host spans) of the trace."""
+    """(device ops, diffusion module events, host spans) of the trace;
+    ops and modules per device plane, in plane order."""
     path = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)[0]
     data = jax.profiler.ProfileData.from_file(path)
     ops, mods, spans = [], [], []
     for plane in data.planes:
         if plane.name.startswith("/device:"):
+            d_ops, d_mods = [], []
             for line in plane.lines:
                 ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
                       for e in line.events]
                 if line.name == "XLA Ops":
-                    ops += ev
+                    d_ops += ev
                 elif line.name == "XLA Modules":
-                    mods += [m for m in ev
-                             if m[0].startswith("jit__run_rounds")]
+                    d_mods += [m for m in ev if m[0].startswith(MODULES)]
+            if d_ops:
+                ops.append(d_ops)
+                mods.append(d_mods)
         elif plane.name.startswith("/host:"):
             spans += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
                       for line in plane.lines for e in line.events
@@ -180,6 +203,12 @@ def read_events(log_dir: str):
 
 
 def reduce_trace(ops, mods, spans, plan, hlo_maps):
+    """Per device: (scope self ns, op self ns, per-query rows)."""
+    return [reduce_device(o, m, spans, plan, hlo_maps)
+            for o, m in zip(ops, mods)]
+
+
+def reduce_device(ops, mods, spans, plan, hlo_maps):
     queries = [s for s in spans if s[0] == trace.QUERY]
     scope_ns, by_op, rows = (collections.Counter(), collections.Counter(),
                              [])
@@ -194,6 +223,7 @@ def reduce_trace(ops, mods, spans, plan, hlo_maps):
             sn = M.short_name(name)
             s = hlo_maps[p].get(sn, "unscoped")
             q_scope[s] += t
+            q_scope["collectives"] += t if is_collective(sn) else 0
             by_op[f"{s}/{sn}"] += t
         for name, t in M.self_times(rest):
             q_scope["unscoped"] += t
@@ -213,6 +243,11 @@ def reduce_trace(ops, mods, spans, plan, hlo_maps):
                                           for k, v in q_scope.items()}})
         scope_ns.update(q_scope)
     return scope_ns, by_op, rows
+
+
+def shares(scope_ns):
+    total = sum(v for k, v in scope_ns.items() if k != "collectives")
+    return {k: 100 * v / total for k, v in scope_ns.items()} if total else {}
 
 
 def innermost_gaps(ops, spans, top: int = 10):
@@ -235,25 +270,42 @@ def innermost_gaps(ops, spans, top: int = 10):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--scale", type=int, default=16)
+    ap.add_argument("--config", default="graph500-s16")
+    ap.add_argument("--scale", type=int, default=None,
+                    help="override the configuration's scale")
     ap.add_argument("--seed", type=int, default=2147483999)
     ap.add_argument("--keys", type=int, default=3)
     ap.add_argument("--out", default="artifacts/trace_probe.json")
     args = ap.parse_args()
 
-    sess, keys = build(args.scale, args.seed, args.keys)
     events = []
-    jax.monitoring.register_scalar_listener(
-        lambda ev, v, **kw: events.append(("read", kw.get("site"), v))
-        if ev == trace.HOST_READ_BYTES else None)
-    jax.monitoring.register_event_duration_secs_listener(
-        lambda ev, d, **_: events.append(("dur", ev[len(trace.EVENT_PREFIX):],
-                                          d))
-        if ev.startswith(trace.EVENT_PREFIX + trace.QUERY) else None)
+
+    def on_scalar(ev, v, **kw):
+        if ev == trace.HOST_READ_BYTES:
+            events.append(("read", kw.get("site"), v))
+        elif ev == trace.EXCHANGE_BYTES:
+            events.append(("exchange", None, v))
+
+    def on_duration(ev, d, **_):
+        if ev.startswith(trace.EVENT_PREFIX + trace.QUERY):
+            events.append(("dur", ev[len(trace.EVENT_PREFIX):], d))
+        elif ev == trace.EVENT_PREFIX + trace.PLACE:
+            events.append(("place", None, d))
+        elif ev == COMPILE_EVENT:
+            events.append(("compile", None, d))
+
+    jax.monitoring.register_scalar_listener(on_scalar)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    t0 = time.perf_counter()
+    sess, keys = build(args.config, args.scale, args.seed, args.keys)
+    setup = {"seconds": time.perf_counter() - t0,
+             "place_s": [e[2] for e in events if e[0] == "place"],
+             "compiles": sum(e[0] == "compile" for e in events)}
 
     plan_untraced = [(p, k) for k in keys[args.keys:] for p in PROGS]
     plan = [(p, k) for k in keys[:args.keys] for p in PROGS]
-    out = {"seed": args.seed, "keys": keys,
+    out = {"config": args.config, "engine": sess.engine, "seed": args.seed,
+           "keys": keys, "setup": setup,
            "untraced": run_queries(sess, plan_untraced, events, False)}
 
     log_dir = tempfile.mkdtemp()
@@ -269,21 +321,35 @@ def main():
     ops, mods, spans = read_events(log_dir)
     if not ops:
         raise SystemExit("the trace holds no device ops")
-    scope_ns, by_op, rows = reduce_trace(ops, mods, spans, plan, hlo_maps)
+    per_dev = reduce_trace(ops, mods, spans, plan, hlo_maps)
+    scope_ns, by_op = collections.Counter(), collections.Counter()
+    for s_ns, o_ns, _ in per_dev:
+        scope_ns.update(s_ns)
+        by_op.update(o_ns)
+    rows = per_dev[0][2]
 
     traced = out["traced"]
-    total = sum(scope_ns.values())
+    n_dev = len(per_dev)
     out.update({
-        "scope_share_pct": {k: 100 * v / total for k, v in scope_ns.items()},
-        "sweep_ms": scope_ns[trace.SCOPE_RELAX] * 1e-6
+        "devices": n_dev,
+        "memory_peak_bytes": [(d.memory_stats() or {}).get(
+            "peak_bytes_in_use") for d in jax.devices()],
+        "scope_share_pct": shares(scope_ns),
+        "scope_share_pct_by_device": [shares(s) for s, _, _ in per_dev],
+        "compiles_per_query": sum(q["compiles"] for q in traced)
+        / len(traced),
+        "exchange_bytes_per_query": sum(q["exchange_bytes"] for q in traced)
+        / len(traced),
+        "sweep_ms": scope_ns[trace.SCOPE_RELAX] * 1e-6 / n_dev
         / sum(q["local_iters"] for q in traced),
         "exchange_ms_per_round": scope_ns[trace.SCOPE_EXCHANGE] * 1e-6
-        / sum(q["rounds"] for q in traced),
+        / n_dev / sum(q["rounds"] for q in traced),
         "query_host_idle_ms": sum(q["idle_ms"] for q in rows) / len(rows),
         "host_reads_per_query": sum(q["reads"] for q in traced)
         / len(traced),
-        "top_ops": [[k, v * 1e-9] for k, v in by_op.most_common(20)],
-        "idle_gaps": innermost_gaps(ops, spans),
+        "top_ops": [[k, v * 1e-9 / n_dev]
+                    for k, v in by_op.most_common(20)],
+        "idle_gaps": innermost_gaps(ops[0], spans),
         "per_query": rows,
     })
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
