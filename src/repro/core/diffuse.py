@@ -201,13 +201,16 @@ def _local_iter_shard(prog: VertexProgram, np_, s_, my_shard, sg_s, st, relax,
     return (vstate, activated, outbox, outbox_has, outbox_pay), counts
 
 
-def _sg_as_dict(sg: ShardedGraph, with_push: bool = False):
+def _sg_as_dict(sg: ShardedGraph, with_push: bool = False,
+                src_gid: bool = True):
     """ShardedGraph -> the engine-facing array dict: the per-cell vertex
     block (``node_ok``/``gid``/``out_degree``) plus the destination-sorted
     pull streams the relax backends consume (``csr_key`` live-masked,
     ``csr_skey`` structural — see DESIGN.md §2.9 — and ``csr_run_end``,
     the sorted region's run layout, :func:`~.msg.run_ends`: computed here
-    once per dispatch, not in every sweep) — and, when
+    once per dispatch, not in every sweep; ``csr_src_gid`` unless
+    ``src_gid`` is False, as the logical engine passes for programs
+    that do not read the source gid) — and, when
     ``with_push`` (any sweep that can compact), the source-sorted push
     streams too (built on demand for graphs with invalidated views).
     The unsorted edge arrays always stay out, and the push streams stay
@@ -222,6 +225,8 @@ def _sg_as_dict(sg: ShardedGraph, with_push: bool = False):
         "out_degree": sg.out_degree,
     }
     d.update(_pull_streams(sg))
+    if not src_gid:
+        del d["csr_src_gid"]
     if with_push:
         d.update(sg.push_view())
     if sg.replica_members is not None:
@@ -255,11 +260,11 @@ def engine_views(sg: ShardedGraph, pull: bool = True,
                  push: bool = False) -> dict:
     """:func:`_sg_as_dict` of a graph with both views, for callers that
     keep it between dispatches (the session's spmd cells): the pull half
-    (vertex block, pull streams, run layout, replica table) and/or the
-    push half.  The gathers and the run layout run as one compiled
-    program (eagerly, the layout's binary search would dispatch op by
-    op); the fields ``_sg_as_dict`` passes through unchanged are the
-    graph's own arrays here, not copies."""
+    (vertex block, pull streams with ``csr_src_gid``, run layout, replica
+    table) and/or the push half.  The gathers and the run layout run as
+    one compiled program (eagerly, the layout's binary search would
+    dispatch op by op); the fields ``_sg_as_dict`` passes through
+    unchanged are the graph's own arrays here, not copies."""
     if sg.csr_perm is None or sg.push_perm is None:
         raise ValueError("engine_views needs both views; call with_csr()")
     d = {}
@@ -399,7 +404,8 @@ def _run_rounds(sg: ShardedGraph, prog: VertexProgram, vstate0, active0,
     lane = (L,) if L else ()
     if sg.csr_perm is None or (sweep != "pull" and sg.push_perm is None):
         sg = sg.with_csr()          # invalidated views: rebuild in-trace
-    sgd = _sg_as_dict(sg, with_push=sweep != "pull")
+    sgd = _sg_as_dict(sg, with_push=sweep != "pull",
+                      src_gid=prog.source_reads().gid)
     # the [G, Rmax] member table rides outside the per-shard vmap below
     rmem = sgd.pop("replica_members", None)
     if rmem is not None:

@@ -696,15 +696,22 @@ class ShardedGraph:
         keeps the structural sorted key so the scan paths'
         ``searchsorted`` run layout survives tombstoning (the delta
         segment of ``csr_skey`` is unsorted — the kernels consume it
-        through a separate scatter pass, never the scan).
+        through a separate scatter pass, never the scan).  ``csr_src_gid``
+        is the source vertex's gid at each position, ``gid[csr_src]``
+        per cell: structural, so a sweep reads it as a stream instead of
+        gathering ``gid`` (the engines keep it only for programs that
+        read the source gid, :meth:`~.programs.VertexProgram.
+        source_reads`).
         """
         if self.csr_perm is None:
             raise ValueError("ShardedGraph has no CSR view; call with_csr()")
         take = lambda a: jnp.take_along_axis(a, self.csr_perm, axis=-1)
+        src = take(self.src_local)
         return {
             "csr_key": jnp.where(self.csr_live, self.csr_key, -1),
             "csr_skey": self.csr_key,
-            "csr_src": take(self.src_local),
+            "csr_src": src,
+            "csr_src_gid": jax.vmap(lambda g, s: g[s])(self.gid, src),
             "csr_weight": take(self.weight),
             "csr_dst_gid": take(self.dst_gid),
         }

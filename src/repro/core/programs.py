@@ -48,7 +48,8 @@ import numpy as np
 from .monoid import Monoid, as_monoid
 
 __all__ = [
-    "Field", "DiffusiveProgram", "VertexProgram", "ProgramSpec",
+    "Field", "DiffusiveProgram", "VertexProgram", "SourceReads",
+    "ProgramSpec",
     "BoundQuery", "ProgramHandle", "diffusive", "lower", "make_laned",
     "PROGRAMS", "register_program", "freeze_kwargs",
     "sssp", "bfs", "cc", "ppr", "pagerank", "widest", "reach",
@@ -94,6 +95,54 @@ def _fn_key(fn):
     return (code, cells, defaults)
 
 
+class SourceReads(NamedTuple):
+    """What one sweep reads of each edge's source vertex, from the
+    program alone (:meth:`VertexProgram.source_reads`).
+
+    ``leaves`` names the state fields ``emit`` or ``payload`` use;
+    ``gid`` says whether they use ``src_gid`` (the engines then serve it
+    as the stream field ``csr_src_gid``); ``flag_leaf`` names the read
+    field whose word carries the sender flag in its sign bit — a signed
+    32-bit field declared non-negative (:class:`Field` ``domain``), or
+    None, and the flag then takes a gather of its own.  ``leaves`` is
+    None for a program without a Field schema, which reads every leaf
+    and packs none.
+    """
+
+    leaves: tuple | None
+    gid: bool
+    flag_leaf: str | None
+
+    @property
+    def gathers(self) -> int | None:
+        """E-long source gathers of one dense sweep: one per read leaf,
+        one for the sender flag unless it rides in ``flag_leaf``."""
+        if self.leaves is None:
+            return None
+        return len(self.leaves) + (self.flag_leaf is None)
+
+
+def _live_inputs(closed) -> list:
+    """Which inputs of a closed jaxpr feed its outputs (an equation's
+    inputs are live when any of its outputs is; nested jaxprs count
+    whole)."""
+    jaxpr = closed.jaxpr
+    live = {id(v) for v in jaxpr.outvars}
+    for eqn in reversed(jaxpr.eqns):  # analysis: allow(host-loop): trace-time walk of emit's jaxpr, once per program, not over cells
+        if any(id(v) in live for v in eqn.outvars):
+            live.update(id(v) for v in eqn.invars)
+    return [id(v) in live for v in jaxpr.invars]
+
+
+def _flag_capable(field) -> bool:
+    """A field whose word has a spare sign bit: signed 32-bit, declared
+    non-negative."""
+    dt = np.dtype(field.dtype)
+    lo = field.domain[0] if field.domain is not None else None
+    return (dt.itemsize == 4 and dt.kind in "fi" and lo is not None
+            and lo >= 0)
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class VertexProgram:
     """Lowered (engine-facing) vertex program.
@@ -130,9 +179,10 @@ class VertexProgram:
     lanes: int | None = None       # lane count; None = single-query program
     name: str = ""
     # the declarative Field schema this program was lowered from, as
-    # ((name, Field), ...) — carried for the session's validate= guard;
-    # None for hand-built programs (which then skip validation).  Not
-    # part of the trace key: nothing the jitted loop reads depends on it.
+    # ((name, Field), ...) — carried for the session's validate= guard
+    # and for source_reads(); None for hand-built programs (which then
+    # skip validation and pack no sender flag).  The trace key holds
+    # only the part source_reads() decides from.
     fields: Any = None
 
     def __post_init__(self):
@@ -145,15 +195,53 @@ class VertexProgram:
 
     def _trace_key(self) -> tuple:
         """Everything the jitted fixed-point loop reads from this
-        program (``init`` excluded — it enters as traced arrays)."""
+        program (``init`` excluded — it enters as traced arrays).  Of
+        the Field schema only what :meth:`source_reads` decides from:
+        names, dtypes and which fields may carry the sender flag."""
         key = self.__dict__.get("_trace_key_cache")
         if key is None:
+            schema = (None if self.fields is None else tuple(
+                (n, np.dtype(f.dtype), _flag_capable(f))
+                for n, f in self.fields))
             key = (self.monoid, np.dtype(self.msg_dtype),
                    _fn_key(self.emit), _fn_key(self.on_send),
                    _fn_key(self.receive), _fn_key(self.payload),
-                   _fn_key(self.priority), self.lanes, self.name)
+                   _fn_key(self.priority), self.lanes, self.name, schema)
             object.__setattr__(self, "_trace_key_cache", key)
         return key
+
+    def source_reads(self) -> SourceReads:
+        """The per-edge source reads of this program's sweep, found by
+        tracing ``emit`` and ``payload`` once over abstract edge
+        streams and keeping the inputs that reach their outputs."""
+        reads = self.__dict__.get("_source_reads_cache")
+        if reads is not None:
+            return reads
+        if self.fields is None:
+            reads = SourceReads(None, True, None)
+        else:
+            names = tuple(n for n, _ in self.fields)
+            edge = lambda dt: jax.ShapeDtypeStruct((8,), dt)
+            state = {n: edge(f.dtype) for n, f in self.fields}
+
+            def messages(s, weight, src_gid, dst_gid):
+                msg = self.emit(s, weight, src_gid, dst_gid)
+                pay = (self.payload(s, src_gid) if self.with_payload
+                       else None)
+                return msg, pay
+
+            closed = jax.make_jaxpr(messages)(
+                state, edge(jnp.float32), edge(jnp.int32), edge(jnp.int32))
+            live = _live_inputs(closed)
+            order = jax.tree_util.tree_leaves({n: n for n in names})
+            used = {n for n, u in zip(order, live) if u}
+            leaves = tuple(n for n in names if n in used)
+            fields = dict(self.fields)
+            flag = next((n for n in leaves if _flag_capable(fields[n])),
+                        None)
+            reads = SourceReads(leaves, live[len(names) + 1], flag)
+        object.__setattr__(self, "_source_reads_cache", reads)
+        return reads
 
     def __eq__(self, other):
         if not isinstance(other, VertexProgram):
@@ -193,6 +281,11 @@ class Field:
     an inf that the domain does not admit) fail validation.  Undeclared
     domains default to NaN-only checking for floats and the payload
     range ``[-1, n_ids)`` for ints (payloads carry gids or -1).
+
+    A lower bound ``lo >= 0`` on a float32 or int32 field is also a
+    promise for every value the field holds during a diffusion: its
+    sign bit stays clear (no negative value, no ``-0.0``), so the sweep
+    may carry the sender flag in it (:class:`SourceReads`).
     """
 
     dtype: Any

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import trace
 from .msg import block_any
 
 __all__ = [
@@ -158,6 +159,14 @@ def make_relax(prog, n_shards: int, n_per_shard: int, block_e: int,
     segment (``ShardedGraph.delta_width``, DESIGN.md §2.9): the scan
     paths scan only the sorted region and fold the staged blocks in
     through a scatter; 0 = delta-free streams.
+
+    The per-edge source reads follow the program
+    (:meth:`~.programs.VertexProgram.source_reads`): a program that
+    reads the source gid takes it from the ``csr_src_gid`` stream of
+    ``sg_s``, and the sender flag rides in a non-negative state word
+    where the program has one.  Building the step records the E-long
+    source gathers of one dense sweep as the scalar event
+    :data:`~.trace.SOURCE_GATHERS`, tagged with the program's name.
     """
     if backend not in RELAX_BACKENDS:
         raise ValueError(
@@ -193,6 +202,11 @@ def make_relax(prog, n_shards: int, n_per_shard: int, block_e: int,
                    if pay is not None else None)
         return table, cnt, pay
 
+    reads = prog.source_reads()
+    if reads.gathers is not None:
+        jax.monitoring.record_scalar(trace.SOURCE_GATHERS, reads.gathers,
+                                     program=prog.name)
+
     def _dense(vstate, senders, sg_s):
         return edge_relax(
             prog, vstate, senders, sg_s["gid"],
@@ -201,6 +215,7 @@ def make_relax(prog, n_shards: int, n_per_shard: int, block_e: int,
             n_keys=n_keys, block_e=block_e, backend=backend,
             interpret=interpret, skey=sg_s.get("csr_skey"),
             delta_e=delta_e, run_end=sg_s.get("csr_run_end"),
+            src_gid=sg_s["csr_src_gid"] if reads.gid else None,
         )
 
     if sweep == "pull":

@@ -16,6 +16,8 @@ against queries of milliseconds to seconds:
 * Each spmd dispatch records the bytes its exchange sent between
   devices as the scalar event :data:`EXCHANGE_BYTES`; placing a graph
   version on the mesh is the stage :data:`PLACE`.
+* Building a relax step records the E-long source gathers of one dense
+  sweep of its program as the scalar event :data:`SOURCE_GATHERS`.
 * The diffusion loop's ``jax.named_scope`` names (:data:`SCOPES`) set
   only op metadata: a device trace or a compiled module attributes each
   device op to the relax sweep, the local apply, the round-end exchange
@@ -61,6 +63,12 @@ HOST_READ_BYTES = EVENT_PREFIX + "host_read_bytes"
 # per spmd dispatch: rounds x the bytes one round's all_to_all sends
 # between devices, from shapes (diffuse.spmd_exchange_bytes)
 EXCHANGE_BYTES = EVENT_PREFIX + "exchange_bytes"
+
+# -- source gathers (jax.monitoring scalar event, tag program=) ----------
+
+# per relax step built (relax.make_relax): the E-long gathers of the
+# source vertex one dense sweep makes (programs.SourceReads.gathers)
+SOURCE_GATHERS = EVENT_PREFIX + "source_gathers"
 
 # -- device scopes (jax.named_scope, in each op's op_name metadata) --------
 

@@ -17,10 +17,11 @@ path:
 
 Blocked-kernel anatomy:
 
-* the per-edge source reads (``vstate[src]``, ``senders[src]``,
-  ``gid[src]``) are one XLA gather ahead of the kernel — Mosaic lowers
+* the per-edge source reads (``vstate[src]`` and the sender flag,
+  packed into one word where the program allows, :func:`~.ref.
+  gather_sources`) are XLA gathers ahead of the kernel — Mosaic lowers
   only 2-D gathers within a tile, not a gather from a whole vertex block
-  held in VMEM;
+  held in VMEM; the source gid arrives as the ``csr_src_gid`` stream;
 * edges arrive in the graph's **blocked-CSR layout** (sorted by
   ``(dst_shard, dst_local)``, ``-1``-padded to a block multiple — see
   ``ShardedGraph.with_csr``), so each block's combine is the dense-rank
@@ -179,18 +180,20 @@ def edge_relax_push_blocks(prog, vstate, senders, gid, sg_push, idx,
 
 
 def edge_relax_blocks(prog, vstate, senders, gid, key, src, weight, dst_gid,
-                      block_e: int, interpret: bool = False):
+                      block_e: int, interpret: bool = False, src_gid=None):
     """Per-block partial tables for one relaxation sweep of one cell.
 
     Inputs are this cell's vertex block ([Np] vstate leaves, ``senders``,
     ``gid``) and its destination-sorted edge streams ([Eb], Eb a multiple
-    of ``block_e``).  Returns (part, cnt, uniq[, pay]) each [Eb]: block
+    of ``block_e``; ``src_gid`` the optional ``csr_src_gid`` stream).
+    Returns (part, cnt, uniq[, pay]) each [Eb]: block
     i's partial table occupies ``[i * block_e, (i + 1) * block_e)`` —
     feed to ``ops._combine_blocks`` for the cross-block phase.
     """
     e = key.shape[0]
     assert e % block_e == 0, "pad the stream via ShardedGraph.with_csr"
-    src_state, src_send, src_gid = gather_sources(vstate, senders, gid, src)
+    src_state, src_send, src_gid = gather_sources(prog, vstate, senders,
+                                                  gid, src, src_gid)
     leaves, treedef = jax.tree_util.tree_flatten(src_state)
     n_in = len(leaves) + 5
     stream = pl.BlockSpec((1, block_e), lambda i: (0, i))

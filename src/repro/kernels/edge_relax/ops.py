@@ -120,7 +120,7 @@ def edge_relax_push(prog, vstate, senders, gid, sg_push, csr_key,
 def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
                n_keys: int, block_e: int, backend: str = "xla",
                interpret: bool = False, skey=None, delta_e: int = 0,
-               run_end=None):
+               run_end=None, src_gid=None):
     """One relaxation sweep of one cell; see module docstring for the
     returned (table, cnt, pay) contract.
 
@@ -138,7 +138,10 @@ def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
     segment in through the shared :func:`~.ref.delta_tables` scatter.
     ``run_end`` is the run layout of ``skey``'s sorted region
     (:func:`~repro.core.msg.run_ends`), computed once per dispatch by the
-    engines; computed per call when omitted."""
+    engines; computed per call when omitted.  ``src_gid`` is the
+    structural ``csr_src_gid`` stream for programs that read the source
+    gid (:func:`~.ref.gather_sources`); the scan kernel, which runs off
+    the TPU only, reads ``gid`` itself."""
     if backend not in RELAX_BACKENDS:
         raise ValueError(
             f"backend must be one of {RELAX_BACKENDS}, got {backend!r}")
@@ -154,7 +157,8 @@ def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
     if backend == "xla":
         return edge_relax_stream(prog, vstate, senders, gid, key, src,
                                  weight, dst_gid, n_keys, skey=skey,
-                                 delta_e=delta_e, run_end=run_end)
+                                 delta_e=delta_e, run_end=run_end,
+                                 src_gid=src_gid)
     if prog.combine == "sum":
         es = key.shape[-1] - delta_e
         if run_end is None:
@@ -170,7 +174,8 @@ def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
             # merged by the monoid — same code the XLA path runs
             cand, send, pay = edge_messages(
                 prog, vstate, senders, gid, key[es:], src[es:],
-                weight[es:], dst_gid[es:])
+                weight[es:], dst_gid[es:],
+                None if src_gid is None else src_gid[es:])
             out = merge_tables(prog, out, delta_tables(
                 prog, cand, send, pay, key[es:], n_keys))
         return out
@@ -181,10 +186,11 @@ def edge_relax(prog, vstate, senders, gid, key, src, weight, dst_gid,
                 prog, vs, sd, gid, key, src, weight, dst_gid,
                 n_keys=n_keys, block_e=block_e, backend=backend,
                 interpret=interpret, skey=skey, delta_e=delta_e,
+                src_gid=src_gid,
             )
         )(vstate, senders)
     part, cnt, uniq, pay = edge_relax_blocks(
         prog, vstate, senders, gid, key, src, weight, dst_gid, block_e,
-        interpret=interpret)
+        interpret=interpret, src_gid=src_gid)
     return _combine_blocks(part, cnt, uniq, pay, n_keys, prog.combine,
                            prog.msg_dtype)

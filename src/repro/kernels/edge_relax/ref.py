@@ -26,6 +26,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from ...core.msg import block_any, identity_for, run_ends, segment_combine
 
@@ -48,12 +50,37 @@ __all__ = [
 ]
 
 
-def gather_sources(vstate, senders, gid, src):
+_SIGN = np.int32(np.iinfo(np.int32).min)     # the sign bit of a word
+
+
+def gather_sources(prog, vstate, senders, gid, src, src_gid=None):
     """Per-edge reads of the source vertex: (state leaves, sender flag,
     gid), each [..., E].  ``vstate`` leaves and ``senders`` may carry
-    leading lane axes, which broadcast through."""
-    src_state = jax.tree_util.tree_map(lambda a: a[..., src], vstate)
-    return src_state, senders[..., src], gid[src]
+    leading lane axes, which broadcast through.
+
+    Where the program has a ``flag_leaf`` (:meth:`~repro.core.programs.
+    VertexProgram.source_reads`: a signed 32-bit field declared
+    non-negative, so its sign bit is always clear) the sender flag rides
+    in that bit: one vertex-length pass sets it, one gather reads word
+    and flag together, and clearing the bit again returns the field's
+    bits exactly.  ``src_gid``, when given, is the structural stream
+    ``csr_src_gid`` (``gid[src]`` at every position), read instead of
+    gathering ``gid``."""
+    flag = prog.source_reads().flag_leaf
+    if flag is None:
+        src_state = jax.tree_util.tree_map(lambda a: a[..., src], vstate)
+        src_send = senders[..., src]
+    else:
+        word = vstate[flag]
+        bits = lax.bitcast_convert_type(word, jnp.int32)
+        packed = jnp.where(senders, bits | _SIGN, bits)[..., src]
+        src_send = packed < 0
+        src_state = {k: v[..., src] for k, v in vstate.items() if k != flag}
+        src_state[flag] = lax.bitcast_convert_type(packed & ~_SIGN,
+                                                   word.dtype)
+    if src_gid is None:
+        src_gid = gid[src]
+    return src_state, src_send, src_gid
 
 
 def emit_messages(prog, src_state, src_send, src_gid, key, weight, dst_gid):
@@ -80,13 +107,16 @@ def emit_messages(prog, src_state, src_send, src_gid, key, weight, dst_gid):
     return cand, send, pay
 
 
-def edge_messages(prog, vstate, senders, gid, key, src, weight, dst_gid):
+def edge_messages(prog, vstate, senders, gid, key, src, weight, dst_gid,
+                  src_gid=None):
     """Gather + emit along the destination-sorted edge stream — the one
     message body of every path (dense, push, scan, delta).  ``senders``
-    and vstate leaves may carry leading lane axes.  Returns
+    and vstate leaves may carry leading lane axes; ``src_gid`` is the
+    optional ``csr_src_gid`` stream (:func:`gather_sources`).  Returns
     (cand, send, pay) as :func:`emit_messages`."""
-    return emit_messages(prog, *gather_sources(vstate, senders, gid, src),
-                         key, weight, dst_gid)
+    return emit_messages(
+        prog, *gather_sources(prog, vstate, senders, gid, src, src_gid),
+        key, weight, dst_gid)
 
 
 def flat_combine(cand, send, pay, ids, n_keys: int, combine: str):
@@ -372,7 +402,7 @@ def stream_combine(prog, cand, send, pay, key, skey, n_keys: int,
 
 def edge_relax_stream(prog, vstate, senders, gid, key, src, weight, dst_gid,
                       n_keys: int, skey=None, delta_e: int = 0,
-                      run_end=None):
+                      run_end=None, src_gid=None):
     """Scan-based relaxation sweep (XLA): gather → emit → segmented scan
     over the sorted region → run-end gather, plus the shared-index
     scatter over the staged delta segment (``delta_e`` trailing
@@ -383,14 +413,15 @@ def edge_relax_stream(prog, vstate, senders, gid, key, src, weight, dst_gid,
     ``key`` is the live-masked destination key (tombstones ``-1``) used
     for send masking; ``skey`` the structural sorted key driving the
     run layout (defaults to ``key`` — identical on delta-free graphs);
-    ``run_end`` its precomputed run ends (:func:`stream_combine`).
+    ``run_end`` its precomputed run ends (:func:`stream_combine`);
+    ``src_gid`` the optional ``csr_src_gid`` stream.
 
     Returns (table [..., n_keys], cnt, pay | None).
     """
     if skey is None:
         skey = key
     cand, send, pay = edge_messages(prog, vstate, senders, gid, key, src,
-                                      weight, dst_gid)
+                                    weight, dst_gid, src_gid)
     return stream_combine(prog, cand, send, pay, key, skey, n_keys,
                           delta_e, run_end)
 

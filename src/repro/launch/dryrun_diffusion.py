@@ -52,6 +52,7 @@ def build_specs(scale: int, n_cells: int, edge_factor: int = 16,
         "csr_key": jax.ShapeDtypeStruct((S, eb), i32),
         "csr_skey": jax.ShapeDtypeStruct((S, eb), i32),
         "csr_src": jax.ShapeDtypeStruct((S, eb), i32),
+        "csr_src_gid": jax.ShapeDtypeStruct((S, eb), i32),
         "csr_weight": jax.ShapeDtypeStruct((S, eb), jnp.float32),
         "csr_dst_gid": jax.ShapeDtypeStruct((S, eb), i32),
         "csr_run_end": jax.ShapeDtypeStruct((S, n), i32),

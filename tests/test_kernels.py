@@ -222,11 +222,13 @@ def _live_eqns(jaxpr, used=None):
 
 
 @pytest.mark.parametrize("delta_e", [0, 256])
-@pytest.mark.parametrize("name,n_reads", [("sssp", 3), ("bfs", 2)])
+@pytest.mark.parametrize("name,n_reads", [("sssp", 1), ("bfs", 1)])
 def test_pull_sweep_has_no_stream_scatters(name, n_reads, delta_e):
     """The dense single-query pull sweep on xla reads the sorted region
     with elementwise scan levels and one gather per destination: its only
-    E-long gathers are the source reads, and only the delta segment takes
+    E-long gather is the one source read — the sender flag rides in the
+    sign bit of ``dist`` and the source gid arrives as the
+    ``csr_src_gid`` stream — and only the delta segment takes
     scatters."""
     import types
 
@@ -243,26 +245,129 @@ def test_pull_sweep_has_no_stream_scatters(name, n_reads, delta_e):
         sds((1, np_), jnp.int32))
     vstate = jax.tree_util.tree_map(lambda a: sds((np_,), a.dtype), vstate)
 
-    def sweep(vs, senders, gid, key, src, w, dg, run_end):
+    def sweep(vs, senders, gid, key, src, w, dg, run_end, src_gid):
         return edge_relax(prog, vs, senders, gid, key, src, w, dg,
                           n_keys=n_keys, block_e=128, backend="xla",
-                          delta_e=delta_e, run_end=run_end)
+                          delta_e=delta_e, run_end=run_end,
+                          src_gid=src_gid)
 
     closed = jax.make_jaxpr(sweep)(
         vstate, sds((np_,), bool), sds((np_,), jnp.int32),
         sds((e,), jnp.int32), sds((e,), jnp.int32), sds((e,), jnp.float32),
-        sds((e,), jnp.int32), sds((n_keys,), jnp.int32))
+        sds((e,), jnp.int32), sds((n_keys,), jnp.int32),
+        sds((e,), jnp.int32))
     eqns = _live_eqns(closed.jaxpr)
     n_idx = lambda eqn: int(np.prod(eqn.invars[1].aval.shape[:-1]))
     long_gathers = [q for q in eqns if q.primitive.name == "gather"
                     and n_idx(q) >= es]
-    assert len(long_gathers) == n_reads
+    assert len(long_gathers) == n_reads == prog.source_reads().gathers
     scatters = [q for q in eqns if q.primitive.name.startswith("scatter")]
     if not delta_e:
         assert scatters == []
     else:
         assert scatters
         assert all(q.invars[2].aval.size == delta_e for q in scatters)
+
+
+def _source_state(prog, rng, shape, np_):
+    """A random vertex state of ``prog``'s Field schema: floats mix +inf,
+    0.0 and positive values (and -inf and negatives where the field
+    declares no lower bound), ints are ids."""
+    vstate = {}
+    for fname, f in prog.fields:
+        if np.dtype(f.dtype).kind == "f":
+            v = (rng.random(shape) * 4).astype(np.float32)
+            v[rng.random(shape) < 0.25] = np.inf
+            v[rng.random(shape) < 0.15] = 0.0
+            if f.domain is None or f.domain[0] is None:
+                v[rng.random(shape) < 0.2] *= -1
+        else:
+            v = rng.integers(0, np_, shape).astype(np.int32)
+        vstate[fname] = jnp.asarray(v)
+    return vstate
+
+
+@pytest.mark.parametrize("name,kw,lanes,delta_e,n_gathers", [
+    ("sssp", {"source": 0}, None, 0, 1),
+    ("sssp", {"source": 0}, None, 256, 1),
+    ("sssp", {"source": 0}, 3, 256, 1),
+    ("bfs", {"source": 0}, None, 256, 1),
+    ("bfs", {"source": 0}, 2, 0, 1),
+    ("cc", {}, None, 256, 2),
+    ("widest", {"source": 0, "track_parents": True}, None, 256, 2),
+    ("ppr", {"source": 0}, None, 0, 2),
+])
+def test_packed_source_read_matches_unpacked(name, kw, lanes, delta_e,
+                                             n_gathers):
+    """The dense sweep's source read — the sender flag in the sign bit
+    of a non-negative state word, the source gid from the
+    ``csr_src_gid`` stream — returns bitwise the (table, cnt, pay) of
+    the body that gathers every leaf, the flag and the gid apart; the
+    programs without such a word (cc, widest) and the laned runs
+    included.  ``/repro/source_gathers`` counts the live E-long source
+    gathers of the sweep."""
+    from repro.core import trace
+    from repro.core.msg import run_ends
+    from repro.core.programs import PROGRAMS, make_laned
+    from repro.core.relax import make_relax
+    from repro.kernels.edge_relax import edge_relax
+    from repro.kernels.edge_relax.ref import emit_messages, stream_combine
+
+    rng = np.random.default_rng(17)
+    np_, s_, es = 160, 2, 128 * 12
+    n_keys = s_ * np_
+    prog = PROGRAMS[name].factory(**kw)
+    if lanes:
+        prog = make_laned([PROGRAMS[name].factory(**{**kw, "source": i})
+                           for i in range(lanes)])
+    shape = (lanes, np_) if lanes else (np_,)
+    key, skey, src, w = _pull_cell(rng, np_, s_, es, delta_e, hub_run=300)
+    gid = jnp.asarray(rng.permutation(np_ * 4)[:np_].astype(np.int32))
+    dst_gid = jnp.where(skey >= 0, skey + 7, -1)
+    vstate = _source_state(prog, rng, shape, np_)
+    senders = jnp.asarray(rng.random(shape) < 0.6)
+    flag = prog.source_reads().flag_leaf
+    if flag is not None:
+        word, sent = np.asarray(vstate[flag]), np.asarray(senders)
+        assert (sent & np.isposinf(word)).any()
+        assert (sent & (word == 0)).any() and (~sent & (word == 0)).any()
+    run_end = run_ends(skey[:es], n_keys)
+
+    def packed(vstate, senders, src_gid):
+        return edge_relax(prog, vstate, senders, gid, key, src, w, dst_gid,
+                          n_keys=n_keys, block_e=128, backend="xla",
+                          skey=skey, delta_e=delta_e, run_end=run_end,
+                          src_gid=src_gid)
+
+    out = packed(vstate, senders, gid[src])
+    src_state = jax.tree_util.tree_map(lambda a: a[..., src], vstate)
+    cand, send, pay = emit_messages(prog, src_state, senders[..., src],
+                                    gid[src], key, w, dst_gid)
+    ref = stream_combine(prog, cand, send, pay, key, skey, n_keys, delta_e,
+                         run_end)
+    assert (out[2] is None) == (ref[2] is None) == (not prog.with_payload)
+    for a, b in zip(out, ref):
+        if b is not None:
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert int(np.asarray(ref[1]).sum()) > 0
+
+    seen = []
+
+    def on_scalar(event, value, **tags):
+        if event == trace.SOURCE_GATHERS:
+            seen.append((tags["program"], value))
+
+    jax.monitoring.register_scalar_listener(on_scalar)
+    try:
+        make_relax(prog, s_, np_, 128, delta_e=delta_e)
+    finally:
+        jax.monitoring.unregister_scalar_listener(on_scalar)
+    assert seen == [(prog.name, n_gathers)]
+    closed = jax.make_jaxpr(packed)(vstate, senders, gid[src])
+    long_gathers = [q for q in _live_eqns(closed.jaxpr)
+                    if q.primitive.name == "gather"
+                    and int(np.prod(q.invars[1].aval.shape[:-1])) >= es]
+    assert len(long_gathers) == n_gathers
 
 
 def _pull_cell(rng, np_, s_, es, delta_e, hub_run):

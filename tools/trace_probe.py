@@ -32,6 +32,11 @@ It prints, and writes as JSON to ``--out``: the share of device self
 time per scope inside ``repro.query``, over all devices and per device;
 each device's peak memory; per query the device-idle time
 inside each ``repro.query.*`` stage; relax time per sub-iteration,
+per program split by the step of the sweep each op belongs to (source
+read, emit, scan, run-end gathers, delta segment: the function of
+``kernels/edge_relax/ref.py`` in the op's source frames, or for a
+gather whose frames the compiler dropped, its output size), the
+``/repro/source_gathers`` count of each program,
 exchange time per round, host reads per query; the top ops as
 ``<scope>/<hlo name>``; and the longest idle gaps of the traced window,
 each named by the innermost ``bench.*`` or ``repro.*`` span it overlaps.
@@ -119,8 +124,65 @@ def run_queries(sess, plan, events, annotate: bool):
     return rows
 
 
-def hlo_scopes(sess, prog_name: str, key: int) -> dict:
-    """HLO instruction name -> scope of the diffusion for one program."""
+# the step of one relax sweep an op belongs to, by the function of
+# kernels/edge_relax/ref.py in its source frames (first match wins)
+PARTS = (("gather_runs", "run_end"), ("delta_tables", "delta"),
+         ("merge_tables", "delta"), ("_block_scan", "scan"),
+         ("stream_scan", "scan"), ("gather_sources", "source_read"),
+         ("emit_messages", "emit"))
+
+
+def frame_parts(txt: str) -> dict:
+    """Stack frame id -> sweep part, from the module's ``FunctionNames``,
+    ``FileLocations`` and ``StackFrames`` tables: the first of
+    :data:`PARTS` among the functions on the frame's chain, else
+    ``other``.  The table prints a frame's parent one above its id (0:
+    the outermost frame has none)."""
+    tables, cur = collections.defaultdict(dict), None
+    for line in txt.splitlines():
+        if line in ("FunctionNames", "FileLocations", "StackFrames"):
+            cur = line
+            continue
+        m = re.match(r"^(\d+) (.*)$", line) if cur else None
+        if not m:
+            cur = None if not line.strip() else cur
+            continue
+        tables[cur][int(m.group(1))] = m.group(2)
+    fn = {k: v.strip('"') for k, v in tables["FunctionNames"].items()}
+    loc_fn = {k: fn.get(int(re.search(r"function_name_id=(\d+)",
+                                      v).group(1)))
+              for k, v in tables["FileLocations"].items()}
+    frames = {k: (int(re.search(r"file_location_id=(\d+)", v).group(1)),
+                  int(re.search(r"parent_frame_id=(\d+)", v).group(1)))
+              for k, v in tables["StackFrames"].items()}
+    out = {}
+    for f in frames:
+        names, seen, cur_f = set(), set(), f
+        while cur_f in frames and cur_f not in seen:
+            seen.add(cur_f)
+            loc, parent = frames[cur_f]
+            names.add(loc_fn.get(loc))
+            cur_f = parent - 1
+        out[f] = next((part for name, part in PARTS if name in names),
+                      "other")
+    return out
+
+
+def gather_sizes(sg, cells: int) -> dict:
+    """Output elements of a module's gathers -> sweep part, for gathers
+    whose source frames the compiler dropped: a source read fills the
+    ``cells`` edge streams, a run-end gather each cell's key space, and
+    the delta segment's its slice of the streams."""
+    w = sg.csr_perm.shape[-1]
+    sizes = {cells * w: "source_read",
+             cells * sg.n_shards * sg.n_per_shard: "run_end"}
+    if sg.delta_width:
+        sizes.setdefault(cells * sg.delta_width, "delta")
+    return sizes
+
+
+def hlo_scopes(sess, prog_name: str, key: int) -> tuple:
+    """:func:`hlo_maps` of the diffusion module of one program."""
     prog = PROGRAMS[prog_name].factory(source=key)
     v0, a0 = initial_state(sess.sg, prog)
     if sess.engine == "spmd":
@@ -131,8 +193,29 @@ def hlo_scopes(sess, prog_name: str, key: int) -> dict:
         txt = _run_rounds.lower(sess.sg, prog, v0, a0, sess.max_local_iters,
                                 sess.max_rounds, None, sess.backend,
                                 sess.sweep).compile().as_text()
-    comp_of, op_of, calls = {}, {}, {}
+    cells = 1 if sess.engine == "spmd" else sess.sg.n_shards
+    return hlo_maps(txt, gather_sizes(sess.sg, cells))
+
+
+def _elements(line: str) -> int | None:
+    """Element count of an instruction's (first) result shape."""
+    m = re.search(r" = \(?[a-z]+\d*\[([\d,]*)\]", line)
+    if not m:
+        return None
+    return int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+
+
+def hlo_maps(txt: str, sizes: dict) -> tuple:
+    """HLO instruction name -> scope of the diffusion, and -> the sweep
+    part (:data:`PARTS`) of a relax op: a fusion takes the part of the
+    gathers or scatters it holds, else the commonest.  A gather, or a
+    fusion holding one, that no frame names takes its part from its
+    output size (``sizes``, :func:`gather_sizes`); a scatter's is the
+    delta segment's, the only scatters of a dense pull sweep."""
+    parts = frame_parts(txt)
+    comp_of, op_of, calls, part_of, size_of = {}, {}, {}, {}, {}
     comp_ops = collections.defaultdict(list)
+    comp_parts = collections.defaultdict(list)
     cur = None
     for line in txt.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
@@ -146,6 +229,12 @@ def hlo_scopes(sess, prog_name: str, key: int) -> dict:
         on = re.search(r'op_name="([^"]*)"', line)
         op_of[name] = on.group(1) if on else None
         comp_of[name] = cur
+        sf = re.search(r"stack_frame_id=(\d+)", line)
+        part_of[name] = parts.get(int(sf.group(1))) if sf else None
+        opcode = re.search(r" = [^ ]+ ([\w\-]+)\(", line)
+        size_of[name] = _elements(line)
+        comp_parts[cur].append((opcode.group(1) if opcode else "",
+                                part_of[name]))
         c = re.search(r"calls=%([\w.\-]+)", line)
         if c:
             calls[name] = c.group(1)
@@ -163,7 +252,16 @@ def hlo_scopes(sess, prog_name: str, key: int) -> dict:
             cnt.update(comp_scopes(sub, seen))
         return cnt
 
-    smap = {}
+    def inner_parts(c, seen):
+        if c in seen:
+            return []
+        seen.add(c)
+        out = list(comp_parts[c])
+        for sub in comp_calls[c]:
+            out += inner_parts(sub, seen)
+        return out
+
+    smap, pmap = {}, {}
     for name, on in op_of.items():
         s = scope_of(on)
         if s == "unscoped" and name in calls:
@@ -172,7 +270,23 @@ def hlo_scopes(sess, prog_name: str, key: int) -> dict:
             if cnt:
                 s = cnt.most_common(1)[0][0]
         smap[name] = s
-    return smap
+        inner = inner_parts(calls[name], set()) if name in calls else []
+        inner.append((name.split(".")[0], part_of[name]))
+        ops = {op for op, _ in inner}
+        moves = [q for op, q in inner if q in ("source_read", "run_end",
+                                               "delta")
+                 and op.startswith(("gather", "scatter"))]
+        known = collections.Counter(q for _, q in inner if q)
+        if moves:
+            pmap[name] = moves[0]
+        elif any(op.startswith("scatter") for op in ops):
+            pmap[name] = "delta"
+        elif "gather" in ops and size_of[name] in sizes:
+            pmap[name] = sizes[size_of[name]]
+        else:
+            pmap[name] = (known.most_common(1)[0][0] if known
+                          else "other")
+    return smap, pmap
 
 
 def read_events(log_dir: str):
@@ -218,11 +332,14 @@ def reduce_device(ops, mods, spans, plan, hlo_maps):
         in_rr = [e for e in inside
                  if any(m[1] <= e[1] and e[2] <= m[2] for m in rr)]
         rest = [e for e in inside if e not in in_rr]
-        q_scope = collections.Counter()
+        q_scope, q_part = collections.Counter(), collections.Counter()
+        smap, pmap = hlo_maps[p]
         for name, t in M.self_times(in_rr):
             sn = M.short_name(name)
-            s = hlo_maps[p].get(sn, "unscoped")
+            s = smap.get(sn, "unscoped")
             q_scope[s] += t
+            if s == trace.SCOPE_RELAX:
+                q_part[pmap.get(sn, "other")] += t
             q_scope["collectives"] += t if is_collective(sn) else 0
             by_op[f"{s}/{sn}"] += t
         for name, t in M.self_times(rest):
@@ -240,9 +357,29 @@ def reduce_device(ops, mods, spans, plan, hlo_maps):
                      "idle_ms_by_stage": {k: v * 1e-6
                                           for k, v in idle.items()},
                      "self_ms_by_scope": {k: v * 1e-6
-                                          for k, v in q_scope.items()}})
+                                          for k, v in q_scope.items()},
+                     "relax_ms_by_part": {k: v * 1e-6
+                                          for k, v in q_part.items()}})
         scope_ns.update(q_scope)
     return scope_ns, by_op, rows
+
+
+def sweep_parts(per_dev, traced, plan) -> dict:
+    """Per program: relax device time per sub-iteration by sweep part
+    (:data:`PARTS`), in ms, averaged over the devices."""
+    out = {}
+    for prog in PROGS:
+        idx = [i for i, (p, _) in enumerate(plan) if p == prog]
+        iters = sum(traced[i]["local_iters"] for i in idx)
+        tot = collections.Counter()
+        for _, _, rows in per_dev:
+            for i in idx:
+                tot.update(rows[i]["relax_ms_by_part"])
+        if iters:
+            out[prog] = {k: v / len(per_dev) / iters
+                         for k, v in sorted(tot.items())}
+            out[prog]["total"] = sum(tot.values()) / len(per_dev) / iters
+    return out
 
 
 def shares(scope_ns):
@@ -278,13 +415,15 @@ def main():
     ap.add_argument("--out", default="artifacts/trace_probe.json")
     args = ap.parse_args()
 
-    events = []
+    events, source_gathers = [], {}
 
     def on_scalar(ev, v, **kw):
         if ev == trace.HOST_READ_BYTES:
             events.append(("read", kw.get("site"), v))
         elif ev == trace.EXCHANGE_BYTES:
             events.append(("exchange", None, v))
+        elif ev == trace.SOURCE_GATHERS:
+            source_gathers[kw.get("program")] = v
 
     def on_duration(ev, d, **_):
         if ev.startswith(trace.EVENT_PREFIX + trace.QUERY):
@@ -345,6 +484,8 @@ def main():
         "exchange_ms_per_round": scope_ns[trace.SCOPE_EXCHANGE] * 1e-6
         / n_dev / sum(q["rounds"] for q in traced),
         "query_host_idle_ms": sum(q["idle_ms"] for q in rows) / len(rows),
+        "source_gathers": source_gathers,
+        "sweep_ms_by_part": sweep_parts(per_dev, traced, plan),
         "host_reads_per_query": sum(q["reads"] for q in traced)
         / len(traced),
         "top_ops": [[k, v * 1e-9 / n_dev]
